@@ -24,10 +24,10 @@ fused score+aggregate stage was once booked at 17ms.
 
 ``--backend`` pins the kernel routes for the whole run (the CI matrix axis):
 ``auto`` keeps per-platform dispatch, ``cpu`` forces the XLA routes,
-``interpret`` forces the Pallas routes in interpret mode (tile configs
-exercised, nothing compiled), ``gpu``/``tpu`` force the compiled Pallas
-routes and SKIP with a reason when the host platform does not match (exit 0
-— a skipped leg is not a failed leg).
+``interpret`` forces the Pallas routes in interpret mode on a CPU host
+(tile configs exercised, nothing compiled), ``gpu``/``tpu`` force the
+compiled Pallas routes; each SKIPS with a reason when the host platform
+does not match (exit 0 — a skipped leg is not a failed leg).
 
 ``--json`` emits a machine-readable record (schema_version 4: stamped with
 the backend axis and a per-kernel ``{name, backend, compiled, tile_config}``
@@ -44,7 +44,6 @@ from __future__ import annotations
 import argparse
 import functools
 import json
-import os
 import re
 import sys
 import time
@@ -60,7 +59,7 @@ from repro.core import vectorized as V
 from repro.core.segments import (
     EMPTY, ChunkOrder, chunk_order, scatter_unique, segment_ids,
 )
-from repro.kernels.capscore.capscore import _INTERPRET_ENV, default_interpret
+from repro.kernels.capscore.capscore import default_interpret
 from repro.kernels.capscore.ops import capscore, capscore_agg, capscore_multi
 from repro.kernels.capscore.tiling import resolve_backend, tile_config
 from repro.kernels.chunksort import sort_with_perm as chunksort_with_perm
@@ -81,9 +80,9 @@ def resolve_backend_axis(axis: str):
     ``skip_reason`` means this leg cannot run on the current host (compiled
     legs on a CPU runner) and the caller should exit 0 without timing.
 
-    The interpret leg sets ``REPRO_CAPSCORE_INTERPRET=1`` — the authoritative
-    env override, read at trace time — so every Pallas route runs the real
-    tile configs through the interpreter.
+    The interpret leg runs the Pallas routes on a CPU host, where interpret
+    mode is the platform's default, so every Pallas route runs the real tile
+    configs through the interpreter.
     """
     plat = jax.default_backend()
     if axis == "auto":
@@ -93,7 +92,9 @@ def resolve_backend_axis(axis: str):
             return None, f"cpu (XLA-route) leg requested on a {plat} host"
         return "xla", None
     if axis == "interpret":
-        os.environ[_INTERPRET_ENV] = "1"
+        if plat != "cpu":
+            return None, (f"interpret leg runs on a cpu host only (found "
+                          f"{plat!r}: Pallas compiles there)")
         return "pallas", None
     if axis in ("gpu", "tpu"):
         if plat != axis:
@@ -106,7 +107,7 @@ def resolve_backend_axis(axis: str):
 def kernel_stamps(kernel_backend: str | None = None):
     """Schema-v4 per-kernel stamps: dispatch route, compiled?, tile config.
 
-    Deterministic given (host platform, backend axis, interpret env) — the
+    Deterministic given (host platform, backend axis) — the
     CI interpret leg diffs these against the committed snapshot."""
     route = resolve_backend(kernel_backend)
     interp = bool(default_interpret())

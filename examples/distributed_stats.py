@@ -48,15 +48,10 @@ def run_mesh_demo():
     from repro.core import distributed as DD
     from repro.core import freqfns as F
     from repro.core.segments import EMPTY
+    from repro.launch.mesh import make_mesh
 
     EMPTY_ = int(EMPTY)
-    try:  # AxisType landed after jax 0.4; default axis types are equivalent
-        from jax.sharding import AxisType
-
-        mesh = jax.make_mesh((len(jax.devices()),), ("data",),
-                             axis_types=(AxisType.Auto,))
-    except ImportError:
-        mesh = jax.make_mesh((len(jax.devices()),), ("data",))
+    mesh = make_mesh((len(jax.devices()),), ("data",))
     rng = np.random.default_rng(0)
     n = len(jax.devices()) * 65536
     keys = (rng.zipf(1.3, size=n) % 100_000).astype(np.int32)

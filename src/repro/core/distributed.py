@@ -80,11 +80,6 @@ def merge_bottomk_multi(keys_a, seeds_a, keys_b, seeds_b, *, cap):
     return _lanewise_merge_bottomk(keys_a, seeds_a, keys_b, seeds_b, cap)
 
 
-def _axis_size(axis_name: str) -> int:
-    return (jax.lax.axis_size(axis_name) if hasattr(jax.lax, "axis_size")
-            else jax.lax.psum(1, axis_name))  # older jax spelling
-
-
 def tree_merge_bottomk(keys, seeds, k: int, axis_name: str):
     """Butterfly (recursive-halving) bottom-k merge across a mesh axis.
 
@@ -95,7 +90,7 @@ def tree_merge_bottomk(keys, seeds, k: int, axis_name: str):
     axis size is a power of two; other sizes fall back to the one-hop
     all_gather merge (same result, O(k P) bytes).
     """
-    size = _axis_size(axis_name)
+    size = jax.lax.axis_size(axis_name)
     if size & (size - 1):
         return allgather_merge_bottomk(keys, seeds, k, axis_name)
     stage = 1
@@ -124,7 +119,7 @@ def tree_merge_bottomk_multi(keys, seeds, cap: int, axis_name: str):
     """Butterfly merge of stacked per-lane summaries ([L, cap] per device):
     each hop exchanges the whole stack once, then merges lane-wise locally.
     Non-power-of-two axes fall back to the all_gather merge."""
-    size = _axis_size(axis_name)
+    size = jax.lax.axis_size(axis_name)
     if size & (size - 1):
         return allgather_merge_bottomk_multi(keys, seeds, cap, axis_name)
     stage = 1
@@ -332,10 +327,8 @@ def pass1_shard(keys_shard, weights_shard, *, kind, l, salt, k, chunk, axis_name
 
     init = (jnp.full((cap,), EMPTY, jnp.int32), jnp.full((cap,), jnp.inf, jnp.float32))
     # mark the carry as varying over the mesh axis (its value depends on the
-    # shard's data from step 1 on); older jax (< pcast) doesn't track varying
-    # axes, so the cast is unnecessary there
-    if hasattr(jax.lax, "pcast"):
-        init = jax.lax.pcast(init, (axis_name,), to="varying")
+    # shard's data from step 1 on)
+    init = jax.lax.pcast(init, (axis_name,), to="varying")
     (skeys, sseeds), _ = jax.lax.scan(body, init, (kshape, wshape, eids))
     if merge == "tree":
         return tree_merge_bottomk(skeys, sseeds, cap, axis_name)
@@ -358,8 +351,6 @@ def make_distributed_two_pass(mesh, *, kind, l, salt, k, chunk, axis_name="data"
     Returns fn(keys [P*n], weights [P*n]) -> (sampled_keys [k+1], seeds [k+1],
     weights [k+1]) replicated.
     """
-    from jax.experimental.shard_map import shard_map
-
     def program(keys, weights):
         def shard_body(kshard, wshard):
             skeys, sseeds = pass1_shard(
@@ -374,7 +365,7 @@ def make_distributed_two_pass(mesh, *, kind, l, salt, k, chunk, axis_name="data"
             w = pass2_shard(kshard.reshape(-1), wshard.reshape(-1), sorted_keys, axis_name=axis_name)
             return sorted_keys[None], sseeds[order][None], w[None]
 
-        return shard_map(
+        return jax.shard_map(
             shard_body, mesh=mesh,
             in_specs=(P(axis_name), P(axis_name)),
             out_specs=(P(axis_name), P(axis_name), P(axis_name)),
@@ -421,8 +412,7 @@ def pass1_shard_multi(keys_shard, weights_shard, *, ls, salt, k, chunk,
 
     init = (jnp.full((L, cap), EMPTY, jnp.int32),
             jnp.full((L, cap), jnp.inf, jnp.float32))
-    if hasattr(jax.lax, "pcast"):
-        init = jax.lax.pcast(init, (axis_name,), to="varying")
+    init = jax.lax.pcast(init, (axis_name,), to="varying")
     (skeys, sseeds), _ = jax.lax.scan(body, init, (kshape, wshape, eids))
     if merge == "tree":
         return tree_merge_bottomk_multi(skeys, sseeds, cap, axis_name)
@@ -447,34 +437,42 @@ def pass2_shard_multi(keys_shard, weights_shard, sampled_sorted, *, axis_name):
     return jax.lax.psum(local, axis_name)
 
 
+def two_pass_multi_shard(kshard, wshard, *, ls, salt, k, chunk, axis_name,
+                         merge="tree"):
+    """One device's body of the multi-l distributed 2-pass program: pass 1
+    over the local shard, the cross-device summary merge, and pass 2 + psum.
+    Returns ([L, k+1] sorted keys, their seeds, their exact weights), equal
+    on every device of the axis."""
+    skeys, sseeds = pass1_shard_multi(
+        kshard, wshard, ls=ls, salt=salt, k=k, chunk=chunk,
+        axis_name=axis_name, merge=merge)
+    # reprolint: disable=RPL002 -- sorts the [L, k+1] sampled summary
+    # once per two-pass program, not per chunk
+    order = jnp.argsort(skeys, axis=1)
+    sorted_keys = jnp.take_along_axis(skeys, order, axis=1)
+    sorted_seeds = jnp.take_along_axis(sseeds, order, axis=1)
+    w = pass2_shard_multi(kshard, wshard, sorted_keys, axis_name=axis_name)
+    return sorted_keys, sorted_seeds, w
+
+
 def make_distributed_two_pass_multi(mesh, *, ls, salt, k, chunk,
                                     axis_name="data", merge="tree"):
     """Build a jitted shard_map program computing the exact distributed
     2-pass sample for EVERY l of the grid in one launch.
 
-    Returns fn(keys [P*n], weights [P*n]) -> (sampled_keys [L, k+1],
-    seeds [L, k+1], weights [L, k+1]) replicated; per lane, keys are sorted
-    ascending (EMPTY-padded) with their seeds and exact pass-2 weights.
+    Returns fn(keys [P*n], weights [P*n]) -> (sampled_keys [P, L, k+1],
+    seeds [P, L, k+1], weights [P, L, k+1]), one identical replica per
+    device; per lane, keys are sorted ascending (EMPTY-padded) with their
+    seeds and exact pass-2 weights.
     """
-    from jax.experimental.shard_map import shard_map
-
     def program(keys, weights):
         def shard_body(kshard, wshard):
-            skeys, sseeds = pass1_shard_multi(
-                kshard.reshape(-1), wshard.reshape(-1),
-                ls=ls, salt=salt, k=k, chunk=chunk,
-                axis_name=axis_name, merge=merge,
-            )
-            # reprolint: disable=RPL002 -- sorts the [L, k+1] sampled summary
-            # once per two-pass program, not per chunk
-            order = jnp.argsort(skeys, axis=1)
-            sorted_keys = jnp.take_along_axis(skeys, order, axis=1)
-            sorted_seeds = jnp.take_along_axis(sseeds, order, axis=1)
-            w = pass2_shard_multi(kshard.reshape(-1), wshard.reshape(-1),
-                                  sorted_keys, axis_name=axis_name)
-            return sorted_keys[None], sorted_seeds[None], w[None]
+            out = two_pass_multi_shard(
+                kshard.reshape(-1), wshard.reshape(-1), ls=ls, salt=salt,
+                k=k, chunk=chunk, axis_name=axis_name, merge=merge)
+            return tuple(a[None] for a in out)
 
-        return shard_map(
+        return jax.shard_map(
             shard_body, mesh=mesh,
             in_specs=(P(axis_name), P(axis_name)),
             out_specs=(P(axis_name), P(axis_name), P(axis_name)),

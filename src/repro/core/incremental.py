@@ -46,13 +46,13 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64 as _enable_x64
 
 from ..kernels.capscore.ops import capscore_agg, capscore_multi
 from .samplers import SampleResult
 from . import segments as SG
 from .segments import EMPTY, chunk_order, normalize_keys  # noqa: F401 (re-export)
 from . import vectorized as VZ
+from .x64 import x64_scope
 
 _EMPTY_INT = int(EMPTY)
 
@@ -653,7 +653,7 @@ def init_pass2(lane_keys: list[np.ndarray], cap: int | None = None):
     keys = np.full((L, cap), _EMPTY_INT, np.int32)
     for j, kk in enumerate(lane_keys):
         keys[j, : len(kk)] = kk
-    with _enable_x64():
+    with x64_scope():
         return jnp.asarray(keys), jnp.zeros((L, cap), jnp.float64)
 
 
@@ -688,7 +688,7 @@ def pass2_accumulate(skeys, acc, keys, weights=None, *, pad_to: int = 256):
     if m != n:
         keys = np.concatenate([keys, np.full(m - n, _EMPTY_INT, np.int32)])
         w = np.concatenate([w, np.zeros(m - n, np.float64)])
-    with _enable_x64():
+    with x64_scope():
         return _pass2_accum_impl(skeys, acc, jnp.asarray(keys), jnp.asarray(w))
 
 

@@ -18,7 +18,6 @@ blocks (float32 native TPU tile); the grid walks row-blocks.  Scalars
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -29,31 +28,15 @@ from jax.experimental.pallas import tpu as pltpu
 from ...core.samplers import SALT_ELEM, SALT_KEYBASE
 from .tiling import TileConfig, tile_config
 
-# legacy aliases: the TPU/interpret-flavor tile shapes now live in the
-# tiling registry; these remain for importers that pin the default shapes
-BLOCK_ROWS = 8
-LANES = 128
-AGG_BN = 256
-AGG_WINDOW = AGG_BN + 8
-
-# env override for the interpret-mode default (CI / debugging): "1"/"true"
-# forces interpret even on a compiled backend, "0"/"false" forces the
-# compiled Mosaic/Triton path
-_INTERPRET_ENV = "REPRO_CAPSCORE_INTERPRET"
-
 
 def default_interpret() -> bool:
-    """Pallas interpret-mode default, derived from the detected backend.
+    """Pallas interpret mode, derived from the platform and nothing else.
 
     False on a real TPU or GPU (the kernels compile through Mosaic resp.
     Triton and actually run fused), True everywhere else (interpret mode is
     the only way the kernels execute on CPU — correctness checking, not
-    speed).  ``REPRO_CAPSCORE_INTERPRET=0/1`` overrides either way; the value
-    is read at trace time, so set it before the first capscore call.
+    speed).
     """
-    env = os.environ.get(_INTERPRET_ENV)
-    if env is not None and env.strip():  # empty string == unset
-        return env.strip().lower() not in ("0", "false", "no", "off")
     return jax.default_backend() not in ("tpu", "gpu")
 
 
@@ -68,20 +51,42 @@ def _compiler_params(cfg: TileConfig, interpret: bool):
     if interpret or not cfg.compiled:
         return None
     if cfg.backend == "tpu":
-        return pltpu.TPUCompilerParams(dimension_semantics=("arbitrary",))
+        return pltpu.CompilerParams(dimension_semantics=("arbitrary",))
     from jax.experimental.pallas import triton as plgpu
-    return plgpu.TritonCompilerParams(num_stages=cfg.num_stages)
+    return plgpu.CompilerParams(num_stages=cfg.num_stages)
 
 
-def _grid_call(kernel, *, cfg, interpret, grid, in_specs, out_specs,
-               out_shape, n_scalars):
+def out_struct(shape, dtype, *operands):
+    """A pallas_call output type that varies over every mesh axis any of
+    ``operands`` varies over — inside ``jax.shard_map`` Pallas needs that
+    stated (``vma``); outside it the set is empty."""
+    vma = frozenset().union(*(jax.typeof(a).vma for a in operands))
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
+
+
+def _scalars(ls, taus, salt):
+    """The kernels' two leading scalar operands: f32 ``[ls..., taus...]``
+    and the int32 bit pattern of the uint32 salt.
+
+    The floats travel as f32, not as i32 bit patterns: Mosaic bitcasts
+    vectors only, so an SMEM scalar read as i32 cannot become an f32 in the
+    kernel.
+    """
+    fs = jnp.concatenate([jnp.asarray(ls, jnp.float32).reshape(-1),
+                          jnp.asarray(taus, jnp.float32).reshape(-1)])
+    return fs, jnp.asarray(salt, jnp.uint32).astype(jnp.int32).reshape(1)
+
+
+def _grid_call(kernel, scalars, *, cfg, interpret, grid, in_specs, out_specs,
+               out_shape):
     """Build the pallas_call for one entry point under a TileConfig.
 
-    Two grid styles, one kernel body: with ``cfg.scalar_prefetch`` the
-    scalars ride Mosaic's SMEM prefetch (``PrefetchScalarGridSpec``);
-    without it they arrive as a plain leading operand whose block covers the
-    whole scalar vector (the Triton route — index maps use ``(i, *_)`` so
-    both arities work).  Either way the kernel sees ``(scalar_ref, *refs)``.
+    Two grid styles, one kernel body: with ``cfg.scalar_prefetch`` the 1-D
+    ``scalars`` ride Mosaic's SMEM prefetch (``PrefetchScalarGridSpec``);
+    without it they arrive as plain leading operands whose blocks cover each
+    whole vector (the Triton route — index maps use ``(i, *_)`` so both
+    arities work).  Either way the kernel sees ``(*scalar_refs, *refs)``;
+    the returned callable takes the scalars first, then the operands.
     """
     kw = {}
     params = _compiler_params(cfg, interpret)
@@ -91,13 +96,13 @@ def _grid_call(kernel, *, cfg, interpret, grid, in_specs, out_specs,
         return pl.pallas_call(
             kernel,
             grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=1, grid=grid,
+                num_scalar_prefetch=len(scalars), grid=grid,
                 in_specs=in_specs, out_specs=out_specs),
             out_shape=out_shape, interpret=interpret, **kw)
-    scalar_spec = pl.BlockSpec((n_scalars,), lambda i, *_: (0,))
+    scalar_specs = [pl.BlockSpec(a.shape, lambda i, *_: (0,)) for a in scalars]
     return pl.pallas_call(
         kernel, grid=grid,
-        in_specs=[scalar_spec] + list(in_specs), out_specs=out_specs,
+        in_specs=scalar_specs + list(in_specs), out_specs=out_specs,
         out_shape=out_shape, interpret=interpret, **kw)
 
 import numpy as np
@@ -122,14 +127,17 @@ def _combine(h, p):
 
 
 def _u01(h):
-    return ((h >> 8).astype(jnp.float32) + 0.5) * jnp.float32(1.0 / 16777216.0)
+    # h >> 8 fits in 24 bits, so the int32 hop is exact (Mosaic has no
+    # direct uint32 -> float32 cast)
+    u = (h >> 8).astype(jnp.int32).astype(jnp.float32)
+    return (u + 0.5) * jnp.float32(1.0 / 16777216.0)
 
 
-def _capscore_kernel(scalar_ref, keys_ref, eids_ref, w_ref, score_ref, delta_ref, entry_ref):
-    # scalars arrive as int32 bit patterns (exact for both floats and salts)
-    l = jax.lax.bitcast_convert_type(scalar_ref[0], jnp.float32)
-    tau = jax.lax.bitcast_convert_type(scalar_ref[1], jnp.float32)
-    salt = scalar_ref[2].astype(jnp.uint32)
+def _capscore_kernel(fs_ref, salt_ref, keys_ref, eids_ref, w_ref, score_ref,
+                     delta_ref, entry_ref):
+    l = fs_ref[0]
+    tau = fs_ref[1]
+    salt = salt_ref[0].astype(jnp.uint32)
 
     keys = keys_ref[...].astype(jnp.uint32)
     eids = eids_ref[...].astype(jnp.uint32)
@@ -154,7 +162,7 @@ def _capscore_kernel(scalar_ref, keys_ref, eids_ref, w_ref, score_ref, delta_ref
 
     rate = jnp.maximum(inv_l, tau)
     delta = e / rate
-    gate = jnp.where(tau * l > 1.0, True, kb < tau)
+    gate = (tau * l > 1.0) | (kb < tau)
     entry = ((delta < w) & gate).astype(jnp.int32)
 
     score_ref[...] = score
@@ -173,7 +181,7 @@ def capscore(keys, eids, weights, l, tau, salt, *, interpret: bool | None = None
       weights: float32 [N].
       l, tau, salt: scalars (traced ok).
       interpret: None (default) resolves via ``default_interpret()`` —
-        compiled on TPU/GPU, interpret elsewhere, env-overridable.
+        compiled on TPU/GPU, interpret elsewhere.
       cfg: tile config (static); None selects the platform flavor from the
         tiling registry.
     Returns:
@@ -191,26 +199,18 @@ def capscore(keys, eids, weights, l, tau, salt, *, interpret: bool | None = None
     keys2 = keys.reshape(shape2d)
     eids2 = eids.reshape(shape2d)
     w2 = weights.reshape(shape2d)
-    scalars = jnp.concatenate(
-        [
-            jax.lax.bitcast_convert_type(jnp.float32(l), jnp.int32).reshape(1),
-            jax.lax.bitcast_convert_type(jnp.float32(tau), jnp.int32).reshape(1),
-            jnp.asarray(salt, jnp.uint32).astype(jnp.int32).reshape(1),
-        ]
-    )
 
     grid = (rows // br,)
     blk = lambda: pl.BlockSpec((br, lanes), lambda i, *_: (i, 0))
-    out_shape = [
-        jax.ShapeDtypeStruct(shape2d, jnp.float32),
-        jax.ShapeDtypeStruct(shape2d, jnp.float32),
-        jax.ShapeDtypeStruct(shape2d, jnp.int32),
-    ]
+    scalars = _scalars(l, tau, salt)
+    operands = (*scalars, keys2, eids2, w2)
+    out_shape = [out_struct(shape2d, dt, *operands)
+                 for dt in (jnp.float32, jnp.float32, jnp.int32)]
     score, delta, entry = _grid_call(
-        _capscore_kernel, cfg=cfg, interpret=interpret, grid=grid,
+        _capscore_kernel, scalars, cfg=cfg, interpret=interpret, grid=grid,
         in_specs=[blk(), blk(), blk()], out_specs=[blk(), blk(), blk()],
-        out_shape=out_shape, n_scalars=3,
-    )(scalars, keys2, eids2, w2)
+        out_shape=out_shape,
+    )(*operands)
     return score.reshape(n), delta.reshape(n), entry.reshape(n)
 
 
@@ -228,12 +228,12 @@ def _make_capscore_multi_kernel(n_l: int):
     vector ops, so the whole l-grid costs barely more than one lane.
     """
 
-    def kernel(scalar_ref, keys_ref, eids_ref, w_ref,
+    def kernel(fs_ref, salt_ref, keys_ref, eids_ref, w_ref,
                score_ref, delta_ref, entry_ref, kb_ref):
         keys = keys_ref[...].astype(jnp.uint32)
         eids = eids_ref[...].astype(jnp.uint32)
         w = w_ref[...]
-        salt = scalar_ref[2 * n_l].astype(jnp.uint32)
+        salt = salt_ref[0].astype(jnp.uint32)
 
         # shared element randomness (independent of l and tau)
         h = _combine(jnp.full_like(eids, _SEED0), eids)
@@ -249,14 +249,14 @@ def _make_capscore_multi_kernel(n_l: int):
         ku = _u01(hk)  # Hash(x) in (0,1); KeyBase = ku / l
 
         for j in range(n_l):
-            l = jax.lax.bitcast_convert_type(scalar_ref[j], jnp.float32)
-            tau = jax.lax.bitcast_convert_type(scalar_ref[n_l + j], jnp.float32)
+            l = fs_ref[j]
+            tau = fs_ref[n_l + j]
             inv_l = 1.0 / l
             kb = ku / l  # division, not *inv_l: bit-identical to the XLA path
             score = jnp.where(v <= inv_l, kb, v)
             rate = jnp.maximum(inv_l, tau)
             delta = e / rate
-            gate = jnp.where(tau * l > 1.0, True, kb < tau)
+            gate = (tau * l > 1.0) | (kb < tau)
             entry = ((delta < w) & gate).astype(jnp.int32)
             score_ref[j] = score
             delta_ref[j] = delta
@@ -295,30 +295,22 @@ def capscore_multi(keys, eids, weights, ls, taus, salt, *, n_l: int,
     keys2 = keys.reshape(shape2d)
     eids2 = eids.reshape(shape2d)
     w2 = weights.reshape(shape2d)
-    scalars = jnp.concatenate(
-        [
-            jax.lax.bitcast_convert_type(jnp.asarray(ls, jnp.float32), jnp.int32).reshape(n_l),
-            jax.lax.bitcast_convert_type(jnp.asarray(taus, jnp.float32), jnp.int32).reshape(n_l),
-            jnp.asarray(salt, jnp.uint32).astype(jnp.int32).reshape(1),
-        ]
-    )
 
     grid = (rows // br,)
     in_blk = lambda: pl.BlockSpec((br, lanes), lambda i, *_: (i, 0))
     out_blk = lambda: pl.BlockSpec((n_l, br, lanes), lambda i, *_: (0, i, 0))
     shape3d = (n_l, rows, lanes)
-    out_shape = [
-        jax.ShapeDtypeStruct(shape3d, jnp.float32),
-        jax.ShapeDtypeStruct(shape3d, jnp.float32),
-        jax.ShapeDtypeStruct(shape3d, jnp.int32),
-        jax.ShapeDtypeStruct(shape3d, jnp.float32),
-    ]
+    scalars = _scalars(ls, taus, salt)
+    operands = (*scalars, keys2, eids2, w2)
+    out_shape = [out_struct(shape3d, dt, *operands)
+                 for dt in (jnp.float32, jnp.float32, jnp.int32, jnp.float32)]
     score, delta, entry, kb = _grid_call(
-        _make_capscore_multi_kernel(n_l), cfg=cfg, interpret=interpret,
-        grid=grid, in_specs=[in_blk(), in_blk(), in_blk()],
+        _make_capscore_multi_kernel(n_l), scalars, cfg=cfg,
+        interpret=interpret, grid=grid,
+        in_specs=[in_blk(), in_blk(), in_blk()],
         out_specs=[out_blk(), out_blk(), out_blk(), out_blk()],
-        out_shape=out_shape, n_scalars=2 * n_l + 1,
-    )(scalars, keys2, eids2, w2)
+        out_shape=out_shape,
+    )(*operands)
     return (score.reshape(n_l, n), delta.reshape(n_l, n),
             entry.reshape(n_l, n), kb.reshape(n_l, n))
 
@@ -329,14 +321,21 @@ def capscore_multi(keys, eids, weights, ls, taus, salt, *, n_l: int,
 
 # block/window sizes for the fused-aggregate kernel come from the tiling
 # registry: the block-local one-hot (window x bn) and the masked reductions
-# over it are the per-block working set (~0.5 MB at bn=256), the
-# embedding_bag segment-sum idiom; the output row window is bn segments +
-# ``align`` slack rows (the dynamic row start is rounded down to a multiple
-# of ``align`` so the store stays tile-aligned; a block of bn sorted
-# elements spans < bn segments)
+# over it are the per-block working set (~0.5 MB at bn=256); the output row
+# window is bn segments + ``align`` slack rows (the dynamic row start is
+# rounded down to a multiple of ``align`` so the store stays tile-aligned; a
+# block of bn sorted elements spans < bn segments)
 
 _EMPTY_KEY = np.int32(2**31 - 1)  # == core.segments.EMPTY (int32 max)
 _NO_ENTRY = np.int32(2**30)       # > any element index: "no entry event"
+_LANE = 128                       # TPU lane width: the packed output's quantum
+
+
+def _agg_columns(n_l: int) -> int:
+    """Width of ``capscore_agg``'s packed output: ``1 + 4 * n_l`` columns
+    (w_total, then entered / contrib / kb_min / min_score, ``n_l`` each)
+    rounded up to whole 128-lane tiles."""
+    return -(-(1 + 4 * n_l) // _LANE) * _LANE
 
 
 def _make_capscore_agg_kernel(n_l: int, bn: int, window: int, align: int):
@@ -345,41 +344,43 @@ def _make_capscore_agg_kernel(n_l: int, bn: int, window: int, align: int):
     Consumes the chunk in KEY-SORTED order (the pre-gathered ``ChunkOrder``
     view): per grid step, one block of ``bn`` elements is scored for all
     ``n_l`` lanes entirely in VMEM, then segment-reduced into the per-key
-    output columns through a block-local one-hot — sums ride the MXU
-    (``onehot @ vals``, the embedding_bag idiom), mins/maxes ride the VPU as
-    masked reductions.  Because ``seg`` is sorted, a block's segments span a
-    contiguous id range, so each block touches one ``window``-row slice
-    of the (fully VMEM-resident) outputs; the slice is read-modify-written,
-    which is the **cross-block carry**: the boundary segment shared with the
-    previous block combines via +/min/max, and the entered-before flag
-    carried in ``ent`` decides the contrib recurrence
-    ``contrib = entered_before ? contrib + block_w : block_contrib``
-    (the first-entry-onward count semantics of Algorithm 4, folded left
-    block by block).
+    output columns through a block-local one-hot as masked VPU reductions
+    (sums, mins, maxes alike).  Because ``seg`` is sorted, a block's
+    segments span a contiguous id range starting at the block's first
+    segment id (``start_ref``, prefetched), so each block touches one
+    ``window``-row slice of the fully VMEM-resident output; the slice is
+    read-modify-written, which is the **cross-block carry**: the boundary
+    segment shared with the previous block combines via +/min/max, and the
+    entered-before flag in the ``entered`` columns decides the contrib
+    recurrence ``contrib = entered_before ? contrib + block_w :
+    block_contrib`` (the first-entry-onward count semantics of Algorithm 4,
+    folded left block by block).
+
+    All five per-key columns share ONE packed f32 output, ``_agg_columns``
+    lanes wide (``entered`` as 0/1): five separate ``[rows, n_l]`` outputs
+    would each pad their lane dimension to 128 in VMEM.
 
     Contract vs the XLA path (``ref.capscore_agg_ref``): min/max columns and
     ``entered`` are bit-identical; the float sums (``w_total``, ``contrib``)
-    are reassociated by the in-block matmul reduce, so they agree up to
-    f32 summation order (tests pin mins exactly and sums to tight rtol).
+    are reassociated by the in-block reduce, so they agree up to f32
+    summation order (tests pin mins exactly and sums to tight rtol).
     """
+    c_ent, c_ctr, c_kbm, c_msc = (1 + q * n_l for q in range(4))
 
-    def kernel(scalar_ref, keys_ref, eids_ref, w_ref, seg_ref,
-               wt_ref, ent_ref, ctr_ref, kbm_ref, msc_ref):
+    def kernel(fs_ref, salt_ref, start_ref, keys_ref, eids_ref, w_ref,
+               seg_ref, acc_ref):
         step = pl.program_id(0)
 
         @pl.when(step == 0)
         def _init():
-            wt_ref[...] = jnp.zeros_like(wt_ref)
-            ent_ref[...] = jnp.zeros_like(ent_ref)
-            ctr_ref[...] = jnp.zeros_like(ctr_ref)
-            kbm_ref[...] = jnp.full_like(kbm_ref, jnp.inf)
-            msc_ref[...] = jnp.full_like(msc_ref, jnp.inf)
+            col = jax.lax.broadcasted_iota(jnp.int32, acc_ref.shape, 1)
+            acc_ref[...] = jnp.where(col >= c_kbm, jnp.inf, 0.0)
 
         keys = keys_ref[...].astype(jnp.uint32)    # (1, BN)
         eids = eids_ref[...].astype(jnp.uint32)
         w = w_ref[...]
         seg = seg_ref[...]                         # (1, BN) int32, sorted
-        salt = scalar_ref[2 * n_l].astype(jnp.uint32)
+        salt = salt_ref[0].astype(jnp.uint32)
 
         # shared element randomness (independent of l and tau)
         h = _combine(jnp.full_like(eids, _SEED0), eids)
@@ -401,35 +402,32 @@ def _make_capscore_agg_kernel(n_l: int, bn: int, window: int, align: int):
         w_live = jnp.where(live, w, 0.0)
 
         # block-local one-hot over the (sublane-aligned) segment window
-        s0 = seg_ref[0, 0]
-        s0a = (s0 // align) * align
+        s0a = pl.multiple_of((start_ref[step] // align) * align, align)
         local = seg - s0a                          # (1, BN) in [0, window)
         oh = (jax.lax.broadcasted_iota(jnp.int32, (window, bn), 0)
               == local)                            # (W, BN) bool
-        ohf = oh.astype(jnp.float32)
         rows = pl.ds(s0a, window)
 
-        seg_sum = lambda vals: jax.lax.dot_general(  # (1, BN) -> (W, 1)
-            ohf, vals, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        seg_sum = lambda vals: jnp.sum(jnp.where(oh, vals, 0.0), axis=1,
+                                       keepdims=True)   # (1, BN) -> (W, 1)
         seg_min = lambda vals: jnp.min(jnp.where(oh, vals, jnp.inf), axis=1,
                                        keepdims=True)
 
         bw = seg_sum(w_live)                       # (W, 1) block weight/segment
-        wt_ref[rows, :] += bw
+        acc_ref[rows, 0:1] += bw
 
         idx = step * bn + jax.lax.broadcasted_iota(
             jnp.int32, (1, bn), 1)
 
         for j in range(n_l):
-            l = jax.lax.bitcast_convert_type(scalar_ref[j], jnp.float32)
-            tau = jax.lax.bitcast_convert_type(scalar_ref[n_l + j], jnp.float32)
+            l = fs_ref[j]
+            tau = fs_ref[n_l + j]
             inv_l = 1.0 / l
             kb = ku / l  # division, not *inv_l: bit-identical to the XLA path
             score = jnp.where(v <= inv_l, kb, v)
             rate = jnp.maximum(inv_l, tau)
             delta = e / rate
-            gate = jnp.where(tau * l > 1.0, True, kb < tau)
+            gate = (tau * l > 1.0) | (kb < tau)
             es = (delta < w) & gate & live
 
             # first entry event per segment, then back to per-element form
@@ -445,19 +443,23 @@ def _make_capscore_agg_kernel(n_l: int, bn: int, window: int, align: int):
                             + jnp.where(at, w - delta, 0.0))
 
             bc = seg_sum(contrib_elem)                          # (W, 1)
-            be = jnp.max(jnp.where(oh, es.astype(jnp.int32), 0), axis=1,
+            be = jnp.max(jnp.where(oh, es.astype(jnp.float32), 0.0), axis=1,
                          keepdims=True)
             ms = seg_min(jnp.where(live, score, jnp.inf))
             bkb = seg_min(jnp.where(live, kb, jnp.inf))
 
-            # cross-block carry: read the window BEFORE updating `ent` so the
-            # contrib recurrence sees "entered in an earlier block"
-            prev_ent = ent_ref[rows, j:j + 1]
-            prev_ctr = ctr_ref[rows, j:j + 1]
-            ctr_ref[rows, j:j + 1] = jnp.where(prev_ent > 0, prev_ctr + bw, bc)
-            ent_ref[rows, j:j + 1] = jnp.maximum(prev_ent, be)
-            kbm_ref[rows, j:j + 1] = jnp.minimum(kbm_ref[rows, j:j + 1], bkb)
-            msc_ref[rows, j:j + 1] = jnp.minimum(msc_ref[rows, j:j + 1], ms)
+            # cross-block carry: read the window BEFORE updating `entered`
+            # so the contrib recurrence sees "entered in an earlier block"
+            ent = pl.ds(c_ent + j, 1)
+            ctr = pl.ds(c_ctr + j, 1)
+            kbm = pl.ds(c_kbm + j, 1)
+            msc = pl.ds(c_msc + j, 1)
+            prev_ent = acc_ref[rows, ent]
+            acc_ref[rows, ctr] = jnp.where(prev_ent > 0,
+                                           acc_ref[rows, ctr] + bw, bc)
+            acc_ref[rows, ent] = jnp.maximum(prev_ent, be)
+            acc_ref[rows, kbm] = jnp.minimum(acc_ref[rows, kbm], bkb)
+            acc_ref[rows, msc] = jnp.minimum(acc_ref[rows, msc], ms)
 
     return kernel
 
@@ -478,13 +480,13 @@ def capscore_agg(ks, eids, ws, seg, ls, taus, salt, *, n_l: int,
       salt: uint32 scalar shared by all lanes.
       cfg: tile config (static); None selects the platform flavor.  The
         element stream is double-buffered across grid steps (Mosaic grid
-        pipeline / Triton num_stages) while the output columns stay resident.
+        pipeline / Triton num_stages) while the output stays resident.
     Returns:
-      (w_total f32 [C + window, 1],
-       entered i32 / contrib f32 / kb_min f32 / min_score f32, each
-       [C + window, n_l]) — segment-id-indexed columns; rows past the
-      real segment count hold the reduction identities (the wrapper slices
-      and transposes).  ``window = cfg.block[1] + cfg.align``.
+      (w_total f32 [C + window], then entered f32 (0/1) / contrib f32 /
+       kb_min f32 / min_score f32, each [C + window, n_l]) —
+      segment-id-indexed rows; rows past the real segment count hold the
+      reduction identities (the wrapper slices and transposes).
+      ``window = cfg.block[1] + cfg.align``.
     """
     if interpret is None:
         interpret = default_interpret()
@@ -494,29 +496,18 @@ def capscore_agg(ks, eids, ws, seg, ls, taus, salt, *, n_l: int,
     window = bn + cfg.align
     C = ks.shape[0]
     assert C % bn == 0, C
-    scalars = jnp.concatenate(
-        [
-            jax.lax.bitcast_convert_type(jnp.asarray(ls, jnp.float32), jnp.int32).reshape(n_l),
-            jax.lax.bitcast_convert_type(jnp.asarray(taus, jnp.float32), jnp.int32).reshape(n_l),
-            jnp.asarray(salt, jnp.uint32).astype(jnp.int32).reshape(1),
-        ]
-    )
+    scalars = _scalars(ls, taus, salt) + (seg[::bn],)  # + block start segs
     view = lambda a: a.reshape(1, C)
     rows_out = C + window
     in_blk = lambda: pl.BlockSpec((1, bn), lambda i, *_: (0, i))
-    out_blk = lambda cols: pl.BlockSpec((rows_out, cols), lambda i, *_: (0, 0))
-    out_shape = [
-        jax.ShapeDtypeStruct((rows_out, 1), jnp.float32),
-        jax.ShapeDtypeStruct((rows_out, n_l), jnp.int32),
-        jax.ShapeDtypeStruct((rows_out, n_l), jnp.float32),
-        jax.ShapeDtypeStruct((rows_out, n_l), jnp.float32),
-        jax.ShapeDtypeStruct((rows_out, n_l), jnp.float32),
-    ]
-    return _grid_call(
-        _make_capscore_agg_kernel(n_l, bn, window, cfg.align), cfg=cfg,
-        interpret=interpret, grid=(C // bn,),
+    shape = (rows_out, _agg_columns(n_l))
+    operands = (*scalars, view(ks), view(eids), view(ws), view(seg))
+    acc = _grid_call(
+        _make_capscore_agg_kernel(n_l, bn, window, cfg.align), scalars,
+        cfg=cfg, interpret=interpret, grid=(C // bn,),
         in_specs=[in_blk(), in_blk(), in_blk(), in_blk()],
-        out_specs=[out_blk(1), out_blk(n_l), out_blk(n_l), out_blk(n_l),
-                   out_blk(n_l)],
-        out_shape=out_shape, n_scalars=2 * n_l + 1,
-    )(scalars, view(ks), view(eids), view(ws), view(seg))
+        out_specs=pl.BlockSpec(shape, lambda i, *_: (0, 0)),
+        out_shape=out_struct(shape, jnp.float32, *operands),
+    )(*operands)
+    return (acc[:, 0],) + tuple(acc[:, 1 + q * n_l:1 + (q + 1) * n_l]
+                                for q in range(4))

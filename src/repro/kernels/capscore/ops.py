@@ -7,10 +7,6 @@ import numpy as np
 
 from ...core.segments import EMPTY
 from .capscore import (
-    AGG_BN,
-    AGG_WINDOW,
-    BLOCK_ROWS,
-    LANES,
     capscore as _kernel,
     capscore_agg as _kernel_agg,
     capscore_multi as _kernel_multi,
@@ -111,5 +107,5 @@ def capscore_agg(ks, eids, ws, seg, ls, taus, salt, *, backend: str | None = Non
                                          interpret=default_interpret(),
                                          cfg=cfg)
     lane_cols = lambda a: a[:n].T  # [rows, n_l] -> [n_l, C]
-    return (wt[:n, 0], lane_cols(ent) > 0, lane_cols(ctr), lane_cols(kbm),
+    return (wt[:n], lane_cols(ent) > 0, lane_cols(ctr), lane_cols(kbm),
             lane_cols(msc))

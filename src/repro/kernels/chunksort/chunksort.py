@@ -1,19 +1,25 @@
-"""Pallas kernels: block-local bitonic sort + cross-block two-run merge.
+"""Pallas kernels: block-local bitonic sort + cross-block bitonic merge.
 
 The chunk-order sort (``segments.ChunkOrder``) is the single shared O(C log C)
 stage of the ingest path — every lane consumes its permutation.  This module
-replaces the XLA ``argsort`` with a two-phase sorting network over
-``(key, index)`` pairs:
+replaces the XLA ``argsort`` with the classic bitonic sorting network over
+``(key, index)`` pairs, cut into VMEM-sized pallas_calls:
 
   phase 1 — block-local sort: the padded chunk is cut into B-element blocks
-    (B = tile config, power of two); each grid step runs a full bitonic
-    network over its block entirely in VMEM, emitting B-long ascending runs.
+    (B = tile config, power of two); each grid step runs the network's
+    stages of sizes 2..B over its block entirely in VMEM.  The direction
+    rule below makes even blocks ascending runs and odd blocks descending
+    ones, so every adjacent pair of blocks is one bitonic sequence.
 
-  phase 2 — cross-block two-run merge: log2(P/B) further pallas_calls; each
-    grid step loads TWO adjacent sorted runs, reverses the second (making the
-    concatenation a single bitonic sequence) and collapses it with log2(2m)
-    compare-exchange stages, doubling the run length per call until one run
-    spans the chunk.
+  phase 2 — cross-block merge: log2(P/B) further pallas_calls; each grid
+    step loads one bitonic sequence of 2m pairs (two adjacent runs of m) and
+    collapses it with the log2(2m) half-cleaner stages of network size 2m,
+    doubling the run length per call until one ascending run spans the
+    chunk.
+
+Direction rule: a compare-exchange group is ascending iff bit ``size`` of
+its elements' GLOBAL position is clear.  At the last size (P) every position
+is below P, so the final run ascends — no reversal or flip stage needed.
 
 Why pairs: the kernels order ``(key, idx)`` tuples lexicographically.  All
 tuples are distinct (``idx`` is a permutation), so the network needs no
@@ -22,12 +28,12 @@ makes the result bit-identical to ``jnp.argsort(keys, stable=True)`` by
 construction, not by numerical accident.  EMPTY (int32 max) needs no special
 casing: it is maximal, so padded tails sort to the end on their own.
 
-Every compare-exchange stage is a vectorized reshape ``(m, 2, s)`` +
-``where`` swap — no data-dependent control flow, no gathers; the network
-shape is fully static per TileConfig, so each tile config is exactly one
-compile (metered by the reprolint retrace budgets).  On lane-narrow stages
-(s < 128) Mosaic pads the relayout; that cost is the known compiled-TPU
-tuning item and does not affect interpret-mode bit-identity.
+Every compare-exchange stage is elementwise over the (1, width) block: each
+position fetches its partner ``stride`` away with two lane rotations
+(``pltpu.roll``) and keeps itself or the partner — no data-dependent control
+flow, no gathers, no relayouts; the network shape is fully static per
+TileConfig, so each tile config is exactly one compile (metered by the
+reprolint retrace budgets).
 """
 from __future__ import annotations
 
@@ -36,47 +42,35 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from ..capscore.capscore import _compiler_params, default_interpret
+from ..capscore.capscore import _compiler_params, default_interpret, out_struct
 from ..capscore.tiling import TileConfig, tile_config
 
 
-def _compare_exchange(keys, idx, stride, size):
-    """One butterfly stage on flat [n] pair arrays.
+def _compare_exchange(keys, idx, pos, stride, size):
+    """One bitonic stage on (1, width) pair blocks at global positions ``pos``.
 
-    Partners sit ``stride`` apart inside contiguous 2*stride groups — the
-    ``(m, 2, stride)`` reshape puts them on the middle axis.  A group is
-    ascending iff bit ``size`` of its first element index is clear (the
-    classic bitonic direction rule; ``size == 0`` means all-ascending, the
-    merge-cascade case) — derived from an in-kernel iota because Pallas
-    kernels cannot close over trace-time arrays.  Pairs are distinct, so the
-    strict lexicographic ``>`` decides both directions.
+    Partners sit ``stride`` apart: the lower one (bit ``stride`` of its
+    position clear) pairs with ``pos + stride``, the upper one with
+    ``pos - stride``.  The group ascends iff bit ``size`` of ``pos`` is
+    clear; the lower slot then keeps the smaller pair and the upper slot the
+    larger (descending groups the reverse).  Pairs are distinct, so the
+    strict lexicographic ``>`` decides every case.
     """
-    m = keys.shape[0] // (2 * stride)
-    k3 = keys.reshape(m, 2, stride)
-    i3 = idx.reshape(m, 2, stride)
-    ka, kb = k3[:, 0, :], k3[:, 1, :]
-    ia, ib = i3[:, 0, :], i3[:, 1, :]
-    a_gt_b = (ka > kb) | ((ka == kb) & (ia > ib))
-    rows = jax.lax.broadcasted_iota(jnp.int32, (m, 1), 0)
-    asc_rows = ((rows * (2 * stride)) & size) == 0
-    swap = jnp.where(asc_rows, a_gt_b, ~a_gt_b)
-    ka2 = jnp.where(swap, kb, ka)
-    kb2 = jnp.where(swap, ka, kb)
-    ia2 = jnp.where(swap, ib, ia)
-    ib2 = jnp.where(swap, ia, ib)
-    keys = jnp.stack([ka2, kb2], axis=1).reshape(-1)
-    idx = jnp.stack([ia2, ib2], axis=1).reshape(-1)
-    return keys, idx
+    width = keys.shape[-1]
+    lower = (pos & stride) == 0
+    partner = lambda a: jnp.where(lower, pltpu.roll(a, width - stride, 1),
+                                  pltpu.roll(a, stride, 1))
+    kp, ip = partner(keys), partner(idx)
+    self_gt = (keys > kp) | ((keys == kp) & (idx > ip))
+    keep = self_gt == (lower != ((pos & size) == 0))
+    return jnp.where(keep, keys, kp), jnp.where(keep, idx, ip)
 
 
 def _bitonic_stages(block: int):
-    """Static (stride, size) schedule of the full bitonic sort network.
-
-    Classic form: for size = 2, 4, .., block, merge 2*size-bitonic runs with
-    strides size/2 .. 1; group direction is bit ``size`` of the element
-    index, constant within each 2*stride-aligned group.
-    """
+    """Static (stride, size) schedule of the block-local sort: for
+    size = 2, 4, .., block, strides size/2 .. 1."""
     stages = []
     size = 2
     while size <= block:
@@ -88,47 +82,29 @@ def _bitonic_stages(block: int):
     return stages
 
 
-def _make_block_sort_kernel(block: int):
-    """Kernel: full bitonic sort of one (1, block) pair block in VMEM."""
-    stages = _bitonic_stages(block)
+def _make_kernel(width: int, stages):
+    """Kernel: run ``stages`` over one (1, width) pair block in VMEM."""
 
     def kernel(k_ref, i_ref, ko_ref, io_ref):
-        k = k_ref[0, :]
-        i = i_ref[0, :]
+        pos = pl.program_id(0) * width + jax.lax.broadcasted_iota(
+            jnp.int32, (1, width), 1)
+        k, i = k_ref[...], i_ref[...]
         for stride, size in stages:
-            k, i = _compare_exchange(k, i, stride, size)
-        ko_ref[0, :] = k
-        io_ref[0, :] = i
+            k, i = _compare_exchange(k, i, pos, stride, size)
+        ko_ref[...] = k
+        io_ref[...] = i
 
     return kernel
 
 
-def _make_merge_kernel(merged: int):
-    """Kernel: merge two adjacent ascending runs of merged/2 pairs.
-
-    Reversing the second run turns the block into one bitonic sequence; a
-    log2(merged)-stage all-ascending butterfly cascade then sorts it — the
-    cross-block carry is the run layout itself (each call halves the run
-    count), so no state crosses grid steps.
-    """
-    half = merged // 2
-    strides = []
-    s = half
-    while s >= 1:
-        strides.append(s)
-        s //= 2
-
-    def kernel(k_ref, i_ref, ko_ref, io_ref):
-        k = k_ref[0, :]
-        i = i_ref[0, :]
-        k = jnp.concatenate([k[:half], k[half:][::-1]])
-        i = jnp.concatenate([i[:half], i[half:][::-1]])
-        for stride in strides:
-            k, i = _compare_exchange(k, i, stride, 0)  # 0: all-ascending
-        ko_ref[0, :] = k
-        io_ref[0, :] = i
-
-    return kernel
+def _merge_stages(merged: int):
+    """Half-cleaner cascade of network size ``merged``: strides merged/2 .. 1."""
+    stages = []
+    stride = merged // 2
+    while stride >= 1:
+        stages.append((stride, merged))
+        stride //= 2
+    return stages
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "interpret"))
@@ -159,7 +135,7 @@ def sort_pairs(keys, idx, *, cfg: TileConfig | None = None,
     params = _compiler_params(cfg, interpret)
     if params is not None:
         kw["compiler_params"] = params
-    pair_shape = [jax.ShapeDtypeStruct((1, P), jnp.int32)] * 2
+    pair_shape = [out_struct((1, P), jnp.int32, keys, idx)] * 2
 
     def run(kernel, width, k2, i2):
         blk = lambda: pl.BlockSpec((1, width), lambda i: (0, i))
@@ -169,9 +145,10 @@ def sort_pairs(keys, idx, *, cfg: TileConfig | None = None,
             out_shape=pair_shape, interpret=interpret, **kw)(k2, i2)
 
     view = lambda a: a.reshape(1, P)
-    k2, i2 = run(_make_block_sort_kernel(block), block, view(keys), view(idx))
+    k2, i2 = run(_make_kernel(block, _bitonic_stages(block)), block,
+                 view(keys), view(idx))
     m = block
     while m < P:
-        k2, i2 = run(_make_merge_kernel(2 * m), 2 * m, k2, i2)
+        k2, i2 = run(_make_kernel(2 * m, _merge_stages(2 * m)), 2 * m, k2, i2)
         m *= 2
     return k2.reshape(P), i2.reshape(P)

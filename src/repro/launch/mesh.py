@@ -5,15 +5,11 @@ from __future__ import annotations
 import jax
 
 
-def make_mesh(shape, axes):
-    """``jax.make_mesh`` with Auto axis types where the jax version has them
-    (older jax has no AxisType and defaults to the equivalent behavior)."""
-    try:
-        from jax.sharding import AxisType
-
-        return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(shape))
-    except ImportError:
-        return jax.make_mesh(shape, axes)
+def make_mesh(shape, axes, *, devices=None):
+    """``jax.make_mesh`` with Auto axis types (over ``devices`` when given)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(shape),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

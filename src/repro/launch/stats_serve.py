@@ -60,6 +60,7 @@ from ..core.segments import HashBucket
 from ..stats.query import BatchResult, Query
 from ..stats.scheduler import ServeConfig, StatsScheduler
 from ..stats.service import MultiTenantStats, StatsConfig, StreamStatsService
+from .compile_cache import enable_compile_cache
 
 
 class StatsServer:
@@ -119,6 +120,60 @@ class StatsServer:
         return done
 
 
+def serve_synthetic(sched: StatsScheduler, rng, *, steps: int,
+                    requests: int, stream_batch: int, ingest_per_step: int,
+                    record=(), log=print):
+    """The synthetic open-loop serve loop: per step, ``ingest_per_step``
+    random tenants submit a Zipf(1.3) impression batch, a Poisson number of
+    (cap T, audience segment) queries arrive for random tenants, and one
+    ``sched.step()`` serves them; the backlog is drained at the end.
+
+    Returns ``(latencies_s, n_finished, streams)`` where ``streams[t]`` is
+    the concatenated key stream submitted for each tenant in ``record``
+    (for checking answers against an exact reference).
+    """
+    n_tenants = sched.service.n_tenants
+    # synthetic ad workload: per-tenant zipf impression streams; advertisers
+    # ask for many (cap T, audience segment) cells — the paper's inherently
+    # many-T many-segment query mix, multiplexed across tenants
+    caps = [1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0]
+    segments = [None] + [HashBucket(8, b) for b in range(8)]
+    arrivals = rng.poisson(requests / steps, size=steps)
+    streams = {t: [] for t in record}
+
+    next_req, finished, lat = 0, 0, []
+    for step in range(steps):
+        for t in rng.choice(n_tenants, size=min(ingest_per_step, n_tenants),
+                            replace=False):
+            keys = (rng.zipf(1.3, size=stream_batch) % 100_000).astype(
+                np.int64)
+            sched.submit_ingest(int(t), keys)
+            if int(t) in streams:
+                streams[int(t)].append(keys)
+        for _ in range(int(arrivals[step])):
+            if next_req >= requests:
+                break
+            sched.submit_query(
+                int(rng.integers(n_tenants)),
+                freqfns.cap(float(rng.choice(caps))),
+                segments[int(rng.integers(len(segments)))])
+            next_req += 1
+        done = sched.step()
+        for rid in done:
+            lat.append(sched.pop_result(rid).latency_s)
+        finished += len(done)
+        if done:
+            log(f"[stats-serve] step {step:3d}: {len(done):3d} queries in "
+                f"one coalesced dispatch, backlog "
+                f"{int(sched.service.backlog_chunks().sum())} chunks")
+    for rid in sched.drain():
+        lat.append(sched.pop_result(rid).latency_s)
+        finished += 1
+    streams = {t: (np.concatenate(v) if v else np.zeros(0, np.int64))
+               for t, v in streams.items()}
+    return lat, finished, streams
+
+
 def main():
     ap = argparse.ArgumentParser(
         description="multi-tenant frequency-cap stats server (synthetic load)")
@@ -134,6 +189,7 @@ def main():
     ap.add_argument("--k", type=int, default=512)
     ap.add_argument("--chunk", type=int, default=2048)
     args = ap.parse_args()
+    enable_compile_cache()
 
     rng = np.random.default_rng(0)
     svc = MultiTenantStats(
@@ -143,43 +199,10 @@ def main():
         max_ingest_per_step=args.ingest_per_step,
         max_queries_per_step=args.max_batch))
 
-    # synthetic ad workload: per-tenant zipf impression streams; advertisers
-    # ask for many (cap T, audience segment) cells — the paper's inherently
-    # many-T many-segment query mix, multiplexed across tenants
-    caps = [1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0]
-    segments = [None] + [HashBucket(8, b) for b in range(8)]
-    arrivals = rng.poisson(args.requests / args.steps, size=args.steps)
-
-    next_req, finished, lat = 0, 0, []
     t0 = time.time()
-    for step in range(args.steps):
-        for t in rng.choice(args.tenants,
-                            size=min(args.ingest_per_step, args.tenants),
-                            replace=False):
-            keys = (rng.zipf(1.3, size=args.stream_batch) % 100_000).astype(
-                np.int64)
-            sched.submit_ingest(int(t), keys)
-        for _ in range(int(arrivals[step])):
-            if next_req >= args.requests:
-                break
-            sched.submit_query(
-                int(rng.integers(args.tenants)),
-                freqfns.cap(float(rng.choice(caps))),
-                segments[int(rng.integers(len(segments)))])
-            next_req += 1
-        done = sched.step()
-        for rid in done:
-            rec = sched.pop_result(rid)
-            lat.append(rec.latency_s)
-        finished += len(done)
-        if done:
-            print(f"[stats-serve] step {step:3d}: {len(done):3d} queries in "
-                  f"one coalesced dispatch, backlog "
-                  f"{int(sched.service.backlog_chunks().sum())} chunks")
-    for rid in sched.drain():
-        rec = sched.pop_result(rid)
-        lat.append(rec.latency_s)
-        finished += 1
+    lat, finished, _ = serve_synthetic(
+        sched, rng, steps=args.steps, requests=args.requests,
+        stream_batch=args.stream_batch, ingest_per_step=args.ingest_per_step)
     dt = time.time() - t0
 
     lat_ms = np.sort(np.asarray(lat)) * 1e3

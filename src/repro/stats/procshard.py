@@ -64,6 +64,7 @@ import tempfile
 import time
 from pathlib import Path
 
+import jax
 import numpy as np
 
 from ..launch.faults import (
@@ -367,6 +368,14 @@ class ShardSupervisor:
 
     def __init__(self, base_config: StatsConfig, root, tier: TierConfig,
                  cfg: SupervisorConfig | None = None):
+        if jax.default_backend() == "tpu":
+            # each worker imports JAX and would need a chip of its own
+            raise RuntimeError(
+                "ProcShardTier cannot start shard worker processes here: "
+                "one process per chip — a TPU chip belongs to one process "
+                "at a time, and this coordinator process already holds the "
+                "chip its workers would need.  Run the in-process ShardTier "
+                "on this host.")
         self.cfg = cfg or SupervisorConfig()
         self.root = Path(root)
         self.tier = tier

@@ -52,11 +52,11 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64 as _enable_x64
 
 from ..core import estimators, freqfns
 from ..core import segments as SEG
 from ..core.samplers import SampleResult
+from ..core.x64 import x64_scope
 
 # per-query estimator form, selected on host by mirroring the branch
 # structure of estimators.estimate:
@@ -236,7 +236,7 @@ class QueryEngine:
             pincl[i, : lane.n] = lane.pincl
         self._one_minus_pincl = 1.0 - pincl  # host [L, K], for the var matvec
         self._has_invprob = any(lane.path == _PATH_INVPROB for lane in self.lanes)
-        with _enable_x64():
+        with x64_scope():
             self._counts = jnp.asarray(counts)
             self._valid = jnp.asarray(valid)
             self._phi = jnp.asarray(phi)
@@ -346,7 +346,7 @@ class QueryEngine:
             fp = np.zeros((T, self.K), np.float64)
             f[: len(self._tab_f_rows)] = np.stack(self._tab_f_rows)
             fp[: len(self._tab_fp_rows)] = np.stack(self._tab_fp_rows)
-            with _enable_x64():
+            with x64_scope():
                 self._segbank_d = jnp.asarray(seg)
                 self._fbank_d = jnp.asarray(f)
                 self._fpbank_d = jnp.asarray(fp)
@@ -411,7 +411,7 @@ class QueryEngine:
         ints, floats, order = self._plan(queries)
         segbank, fbank, fpbank = self._banks()
         use_tabs = bool(ints[4].any())
-        with _enable_x64():
+        with x64_scope():
             per_key = _dispatch(
                 self._counts, self._valid, self._phi,
                 segbank, fbank, fpbank, jnp.asarray(ints), jnp.asarray(floats),

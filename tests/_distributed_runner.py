@@ -18,17 +18,13 @@ from repro.core import distributed as DD  # noqa: E402
 from repro.core import vectorized as V  # noqa: E402
 from repro.core.samplers import shard_eids_np  # noqa: E402
 from repro.core.segments import EMPTY  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 
 EMPTY = int(EMPTY)
 
 
 def _make_mesh():
-    try:  # AxisType landed after jax 0.4; default axis types are equivalent
-        from jax.sharding import AxisType
-
-        return jax.make_mesh((NDEV,), ("data",), axis_types=(AxisType.Auto,))
-    except ImportError:
-        return jax.make_mesh((NDEV,), ("data",))
+    return make_mesh((NDEV,), ("data",))
 
 
 def _reference(keys, w, l, salt):

@@ -1,28 +1,8 @@
 """Test fixtures. NOTE: no XLA_FLAGS here — smoke tests and benches must see
 exactly 1 CPU device (the 512-device mesh lives only in launch/dryrun.py and
 subprocess-based distributed tests)."""
-import sys
-
 import numpy as np
 import pytest
-
-try:  # pragma: no cover - exercised implicitly at collection
-    import hypothesis  # noqa: F401
-except ModuleNotFoundError:
-    # Containers without hypothesis still run the suite: register the
-    # deterministic stub (see tests/_hypothesis_stub.py) before any test
-    # module does `from hypothesis import given`.
-    import importlib.util
-    from pathlib import Path
-
-    _spec = importlib.util.spec_from_file_location(
-        "_hypothesis_stub", Path(__file__).parent / "_hypothesis_stub.py"
-    )
-    _hypothesis_stub = importlib.util.module_from_spec(_spec)
-    _spec.loader.exec_module(_hypothesis_stub)
-
-    sys.modules["hypothesis"] = _hypothesis_stub
-    sys.modules["hypothesis.strategies"] = _hypothesis_stub.strategies
 
 
 @pytest.fixture(scope="session")

@@ -347,7 +347,7 @@ def test_capscore_agg_xla_bit_identity(C, n_keys, L):
 def test_capscore_agg_pallas_matches_xla():
     """The Pallas kernel (interpret mode on CPU) agrees with the XLA path:
     exactly on entered/min/max columns, to f32-reassociation on the sums
-    (the in-block one-hot matmul reduces in a different order)."""
+    (the in-block one-hot reduce sums in a different order)."""
     rng = np.random.default_rng(31)
     for C, n_keys, n_l in [(300, 40, 3), (1024, 200, 1), (2048, 3000, 4)]:
         keys = rng.integers(0, n_keys, C).astype(np.int32)
@@ -615,14 +615,14 @@ def test_update_multi_tau_inf_edge():
     np.testing.assert_array_equal(np.asarray(st_new.bk_seeds), np.asarray(st_ref.bk_seeds))
 
 
-def test_default_interpret_backend_and_env(monkeypatch):
+def test_default_interpret_follows_platform(monkeypatch):
+    """Interpret mode is decided by the platform alone: compiled Pallas on
+    TPU/GPU, interpret everywhere else."""
     from repro.kernels.capscore import capscore as K
 
-    monkeypatch.delenv(K._INTERPRET_ENV, raising=False)
-    # this suite runs on CPU: auto must pick interpret mode
-    assert jax.default_backend() != "tpu"
+    # this suite runs on CPU: the default must pick interpret mode
+    assert jax.default_backend() == "cpu"
     assert K.default_interpret() is True
-    monkeypatch.setenv(K._INTERPRET_ENV, "0")
-    assert K.default_interpret() is False
-    monkeypatch.setenv(K._INTERPRET_ENV, "1")
-    assert K.default_interpret() is True
+    for platform, interpret in (("tpu", False), ("gpu", False), ("cpu", True)):
+        monkeypatch.setattr(K.jax, "default_backend", lambda p=platform: p)
+        assert K.default_interpret() is interpret, platform

@@ -134,3 +134,20 @@ def test_chaos_schedule_realized_against_processes(tmp_path):
         assert fired, "chaos schedule never fired"
     oracle = _oracle_exact(batches, tmp_path / "oracle")
     assert np.array_equal(res.estimates, oracle.estimates)
+
+
+def test_refuses_worker_processes_when_coordinator_holds_tpu(monkeypatch,
+                                                             tmp_path):
+    """On a TPU host the coordinator already holds the chip every worker
+    process would need: construction fails loudly, naming the rule, before
+    any worker is spawned — no restart loop into degraded answers."""
+    from repro.stats import procshard
+
+    spawned = []
+    monkeypatch.setattr(procshard.jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(procshard.ShardProcess, "spawn",
+                        lambda self, *a, **k: spawned.append(self.shard_id))
+    with pytest.raises(RuntimeError, match="one process per chip"):
+        ProcShardTier(CFG, TierConfig(n_shards=2, fsync=False),
+                      tmp_path / "tier")
+    assert spawned == []

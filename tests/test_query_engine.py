@@ -121,7 +121,7 @@ def test_service_exact_path_bit_identical(zipf_stream):
         np.testing.assert_array_equal(ref, lane.counts)
 
 
-@settings(max_examples=12)
+@settings(max_examples=12, deadline=None)
 @given(T=st.floats(min_value=0.5, max_value=200),
        salt=st.integers(min_value=0, max_value=2**31 - 1),
        seg_mod=st.integers(min_value=1, max_value=7))
@@ -288,3 +288,23 @@ def test_pick_l_warns_once_outside_sqrt2_factor():
     with warnings.catch_warnings():  # second offence: silent (warn once)
         warnings.simplefilter("error")
         assert svc.pick_l(2000.0) == 64.0
+
+
+def test_x64_scope_keeps_f64_on_an_ieee_device(monkeypatch, lanes):
+    """On a TPU (emulated f64) the query plane's f64 steps run on the host
+    CPU device; the engine's answers stay bit-identical to the scalar loop."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.x64 import x64_scope
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cpu = jax.devices("cpu")[0]
+    with x64_scope():
+        x = jnp.asarray(np.arange(3, dtype=np.float64))
+    assert x.dtype == jnp.float64 and x.devices() == {cpu}
+    eng = QueryEngine(lanes)
+    qs = [Query(fn, None, l) for l in lanes for fn in FNS]
+    res = eng.query_batch(qs)
+    for q, est in zip(qs, res.estimates):
+        assert float(est) == E.estimate(lanes[q.l], q.fn, q.segment)

@@ -233,6 +233,19 @@ def test_rpl004_inside_enable_x64_good():
     assert "RPL004" not in codes(src)
 
 
+def test_rpl004_inside_jax_enable_x64_true_good():
+    """The ``jax.enable_x64(True)`` context form opens an x64 scope too."""
+    src = """
+        import jax
+        import jax.numpy as jnp
+
+        def acc():
+            with jax.enable_x64(True):
+                return jnp.zeros((4,), jnp.float64)
+    """
+    assert "RPL004" not in codes(src)
+
+
 def test_rpl004_out_of_scope_good():
     src = """
         import jax.numpy as jnp
