@@ -13,10 +13,10 @@ generations:
   trajectory point stays measurable after src moved on;
 * ``fused``: the current permute-once / score-ordered / reduce-fused path.
 
-Per-stage timings are **jitted** closures timed by **min-of-rounds**
-(matching query_throughput.py) — the previous single-shot wall times mostly
-measured eager dispatch overhead and machine noise, which is how a ~0.2ms
-fused score+aggregate stage was once booked at 17ms.
+Timings are **jitted** closures timed by **min-of-rounds** (matching
+query_throughput.py).  All of them are XLA:CPU or interpret-mode numbers;
+the chunk step's per-stage device time is read on the chip from the
+program's stage scopes (``bench/metrics/*_us.py``, PERF.md).
 
     PYTHONPATH=src python -m benchmarks.sampler_throughput \
         [--smoke] [--json PATH] [--backend {auto,cpu,gpu,tpu,interpret}] \
@@ -57,12 +57,11 @@ from repro.core import incremental as I
 from repro.core import samplers as S
 from repro.core import vectorized as V
 from repro.core.segments import (
-    EMPTY, ChunkOrder, chunk_order, scatter_unique, segment_ids,
+    EMPTY, ChunkOrder, scatter_unique, segment_ids,
 )
 from repro.kernels.capscore.capscore import default_interpret
-from repro.kernels.capscore.ops import capscore, capscore_agg, capscore_multi
+from repro.kernels.capscore.ops import capscore, capscore_multi
 from repro.kernels.capscore.tiling import resolve_backend, tile_config
-from repro.kernels.chunksort import sort_with_perm as chunksort_with_perm
 
 SCHEMA_VERSION = 4
 
@@ -312,100 +311,7 @@ _update_multi_sorted = functools.partial(
 # ---------------------------------------------------------------------------
 
 
-def _stage_timings(L, k, chunk, reps=5, backend=None):
-    """Min-of-rounds timings of each JITTED pipeline stage, fused vs legacy.
-
-    Every stage is compiled before timing; what remains is the device compute
-    the scan body actually pays.  The share of the chunk budget spent on
-    score+aggregate is reported against one full fused chunk step.
-    ``backend`` pins every kernel route (score, aggregate, chunk sort) to one
-    leg of the CI matrix; None keeps per-platform dispatch.
-    """
-    ls = jnp.asarray(np.geomspace(1.0, 2.0 ** (L - 1), L), jnp.float32)
-    ck = jnp.asarray(_zipf(chunk, seed=3)[:chunk], jnp.int32)
-    cw = jnp.ones(chunk, jnp.float32)
-    eids = jnp.arange(chunk, dtype=jnp.int32)
-    salt = jnp.uint32(1)
-
-    # a warmed, representative state: ingest a few chunks so tau is finite
-    state, spec = I.init_multi_state(np.asarray(ls), k=k, chunk=chunk, salt=1,
-                                     backend=backend)
-    warm = _zipf(chunk * 4, seed=5).astype(np.int32)
-    state = I.update_multi(state, warm, np.ones(len(warm), np.float32), spec,
-                           donate=False)
-    table = state.table
-    cap_bk = state.bk_keys.shape[1]
-
-    j_order = jax.jit(lambda c, e, w: chunk_order(c, e, w,
-                                                  sort_backend=backend))
-    order = j_order(ck, eids, cw)
-    j_sort = jax.jit(lambda c: chunksort_with_perm(c, backend=backend))
-    j_sort(ck)
-    j_score = jax.jit(lambda: capscore_multi(ck, eids, cw, ls, table.tau, salt,
-                                             backend=backend))
-    score = j_score()[0]
-    j_fused = jax.jit(lambda: capscore_agg(order.ks, order.eids, order.ws,
-                                           order.seg, ls, table.tau, salt,
-                                           backend=backend))
-    cols = j_fused()
-
-    def agg_shared():
-        s, d, e, kb = capscore_multi(ck, eids, cw, ls, table.tau, salt,
-                                     backend=backend)
-        return jax.vmap(
-            lambda s_, d_, e_, b_: V.aggregate_continuous_scored(
-                ck, cw, s_, d_, e_, b_, order)
-        )(s, d, e, kb)
-
-    j_agg_shared = jax.jit(agg_shared)
-
-    def lane_aggs():
-        w_total, entered, contrib, kb_min, min_score = cols
-        return jax.vmap(lambda en, ct, kbm, ms: V.ChunkAgg(
-            ukeys=order.ukeys, w_total=w_total, entered=en, contrib=ct,
-            kb=kbm, min_score=ms))(entered, contrib, kb_min, min_score)
-
-    aggs = jax.jit(lane_aggs)()
-
-    j_merge = jax.jit(lambda t, a: jax.vmap(V.fixed_k_merge)(t, a))
-    merged = j_merge(table, aggs)
-    j_evict_rank = jax.jit(lambda t: jax.vmap(
-        lambda tt, l: V.evict_table(tt, k=k, l=l, salt=salt, max_evict=chunk,
-                                    select="rank"))(t, ls))
-    j_evict_topk = jax.jit(lambda t: jax.vmap(
-        lambda tt, l: V.evict_table(tt, k=k, l=l, salt=salt, max_evict=chunk,
-                                    select="topk"))(t, ls))
-
-    bkk, bks = jax.vmap(V.summary_to_keysorted)(state.bk_keys, state.bk_seeds)
-    j_pass1_fold = jax.jit(lambda b1, b2: jax.vmap(
-        lambda sk, ss, mn: V.pass1_fold_keysorted(sk, ss, order.ukeys, mn, cap_bk)
-    )(b1, b2, cols[4]))
-    j_pass1_legacy = jax.jit(lambda b1, b2: V.pass1_step_multi(
-        (b1, b2), ck, score, cap=cap_bk, order=order))
-
-    # one whole fused chunk step — the budget the shares are measured against
-    j_chunk = functools.partial(I.update_multi, donate=False)
-
-    stages = {
-        "order(1 sort + pre-gather)": lambda: j_order(ck, eids, cw),
-        "sort-only[chunk-order route]": lambda: j_sort(ck),
-        "score+aggregate[fused capscore_agg]": j_fused,
-        "score+aggregate[legacy: score, gather x4L]": j_agg_shared,
-        "merge[sorted-runs, L lanes]": lambda: j_merge(table, aggs),
-        "evict[rank-select]": lambda: j_evict_rank(merged),
-        "evict[legacy top_k]": lambda: j_evict_topk(merged),
-        "pass1[key-sorted fold]": lambda: j_pass1_fold(bkk, bks),
-        "pass1[legacy seed-sorted merge]": lambda: j_pass1_legacy(state.bk_keys, state.bk_seeds),
-        "full chunk step[fused]": lambda: j_chunk(state, ck, cw, spec),
-    }
-    out = {name: bench(fn, reps=reps) * 1e3 for name, fn in stages.items()}
-    chunk_ms = out["full chunk step[fused]"]
-    out["score_agg_share_of_chunk"] = (
-        out["score+aggregate[fused capscore_agg]"] / chunk_ms if chunk_ms else 0.0)
-    return out
-
-
-def multi_lane_ingest(L=8, k=4096, chunk=4096, n_chunks=4, reps=3, stage_reps=5,
+def multi_lane_ingest(L=8, k=4096, chunk=4096, n_chunks=4, reps=3,
                       backend=None):
     """Elements/s of the three ingest generations, min-of-rounds interleaved.
 
@@ -440,7 +346,6 @@ def multi_lane_ingest(L=8, k=4096, chunk=4096, n_chunks=4, reps=3, stage_reps=5,
             jax.tree.map(lambda x: x.block_until_ready(), jax.tree.leaves(out))
             best[name] = min(best[name], time.perf_counter() - t0)
 
-    stages = _stage_timings(L, k, chunk, reps=stage_reps, backend=backend)
     return {
         "L": L, "k": k, "chunk": chunk, "n": n,
         "reference_eps": n / best["reference"],
@@ -448,8 +353,6 @@ def multi_lane_ingest(L=8, k=4096, chunk=4096, n_chunks=4, reps=3, stage_reps=5,
         "fused_eps": n / best["fused"],
         "speedup_vs_reference": best["reference"] / best["fused"],
         "speedup_vs_sorted": best["sorted"] / best["fused"],
-        "score_agg_share": stages["score_agg_share_of_chunk"],
-        "stages_ms": stages,
     }
 
 
@@ -462,12 +365,6 @@ def print_ingest(res):
     print(f"{'update_multi[fused score-in-key-order]':42s} {res['fused_eps']:14.0f}")
     print(f"speedup vs reference: {res['speedup_vs_reference']:.2f}x   "
           f"vs pre-fuse sorted: {res['speedup_vs_sorted']:.2f}x")
-    print(f"\n{'per-stage (jitted, min-of-rounds)':42s} {'ms':>10s}")
-    for name, ms in res["stages_ms"].items():
-        if name == "score_agg_share_of_chunk":
-            print(f"{'score+aggregate share of chunk step':42s} {ms:10.1%}")
-        else:
-            print(f"{name:42s} {ms:10.3f}")
 
 
 def main(n=200_000, k=256, l=20.0, ingest_kw=None, json_path=None,
@@ -510,10 +407,7 @@ def main(n=200_000, k=256, l=20.0, ingest_kw=None, json_path=None,
             "kernels": kernel_stamps(kernel_backend),
             "reprolint": reprolint_stamp(),
             "single_lane": {name: {"elements_per_s": eps} for name, eps, _ in rows},
-            "multi_lane_ingest": {
-                k_: v for k_, v in ingest.items() if k_ != "stages_ms"
-            },
-            "multi_lane_stages_ms": ingest["stages_ms"],
+            "multi_lane_ingest": ingest,
         }
         with open(json_path, "w") as f:
             json.dump(record, f, indent=2)
@@ -571,8 +465,7 @@ if __name__ == "__main__":
                   kernel_backend=kernel_backend)
     if args.smoke:
         main(n=50_000, k=128,
-             ingest_kw=dict(L=4, k=512, chunk=1024, n_chunks=2, reps=3,
-                            stage_reps=2),
+             ingest_kw=dict(L=4, k=512, chunk=1024, n_chunks=2, reps=3),
              perf_gate=True, **common)
     else:
         main(n=2_000_000 if args.full else 200_000,

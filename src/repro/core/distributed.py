@@ -40,6 +40,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from .. import obs
 from .segments import (
     EMPTY,
     compact_valid,
@@ -397,7 +398,9 @@ def pass1_shard_multi(keys_shard, weights_shard, *, ls, salt, k, chunk,
     n_chunks = n // chunk
     kshape = keys_shard.reshape(n_chunks, chunk)
     wshape = weights_shard.reshape(n_chunks, chunk)
-    eids = VZ.shard_eids(shard_no, jnp.arange(n, dtype=jnp.int32)).reshape(n_chunks, chunk)
+    with obs.stage("pass1_score"):
+        eids = VZ.shard_eids(shard_no, jnp.arange(n, dtype=jnp.int32)
+                             ).reshape(n_chunks, chunk)
 
     ls = jnp.asarray(ls, jnp.float32)
     L = ls.shape[0]
@@ -407,16 +410,19 @@ def pass1_shard_multi(keys_shard, weights_shard, *, ls, salt, k, chunk,
 
     def body(carry, xs):
         ck, cw, ce = xs
-        score, _, _, _ = capscore_multi(ck, ce, cw, ls, taus, salt)
-        return VZ.pass1_step_multi(carry, ck, score, cap=cap), None
+        with obs.stage("pass1_score"):
+            score, _, _, _ = capscore_multi(ck, ce, cw, ls, taus, salt)
+        with obs.stage("pass1_fold"):
+            return VZ.pass1_step_multi(carry, ck, score, cap=cap), None
 
     init = (jnp.full((L, cap), EMPTY, jnp.int32),
             jnp.full((L, cap), jnp.inf, jnp.float32))
     init = jax.lax.pcast(init, (axis_name,), to="varying")
     (skeys, sseeds), _ = jax.lax.scan(body, init, (kshape, wshape, eids))
-    if merge == "tree":
-        return tree_merge_bottomk_multi(skeys, sseeds, cap, axis_name)
-    return allgather_merge_bottomk_multi(skeys, sseeds, cap, axis_name)
+    with obs.stage("exchange"):
+        if merge == "tree":
+            return tree_merge_bottomk_multi(skeys, sseeds, cap, axis_name)
+        return allgather_merge_bottomk_multi(skeys, sseeds, cap, axis_name)
 
 
 def pass2_shard_multi(keys_shard, weights_shard, sampled_sorted, *, axis_name):
@@ -446,12 +452,14 @@ def two_pass_multi_shard(kshard, wshard, *, ls, salt, k, chunk, axis_name,
     skeys, sseeds = pass1_shard_multi(
         kshard, wshard, ls=ls, salt=salt, k=k, chunk=chunk,
         axis_name=axis_name, merge=merge)
-    # reprolint: disable=RPL002 -- sorts the [L, k+1] sampled summary
-    # once per two-pass program, not per chunk
-    order = jnp.argsort(skeys, axis=1)
-    sorted_keys = jnp.take_along_axis(skeys, order, axis=1)
-    sorted_seeds = jnp.take_along_axis(sseeds, order, axis=1)
-    w = pass2_shard_multi(kshard, wshard, sorted_keys, axis_name=axis_name)
+    with obs.stage("pass2"):
+        # reprolint: disable=RPL002 -- sorts the [L, k+1] sampled summary
+        # once per two-pass program, not per chunk
+        order = jnp.argsort(skeys, axis=1)
+        sorted_keys = jnp.take_along_axis(skeys, order, axis=1)
+        sorted_seeds = jnp.take_along_axis(sseeds, order, axis=1)
+        w = pass2_shard_multi(kshard, wshard, sorted_keys,
+                              axis_name=axis_name)
     return sorted_keys, sorted_seeds, w
 
 
@@ -463,7 +471,8 @@ def make_distributed_two_pass_multi(mesh, *, ls, salt, k, chunk,
     Returns fn(keys [P*n], weights [P*n]) -> (sampled_keys [P, L, k+1],
     seeds [P, L, k+1], weights [P, L, k+1]), one identical replica per
     device; per lane, keys are sorted ascending (EMPTY-padded) with their
-    seeds and exact pass-2 weights.
+    seeds and exact pass-2 weights.  Each call is a ``twopass.job`` span
+    (``TwoPassProgram``).
     """
     def program(keys, weights):
         def shard_body(kshard, wshard):
@@ -478,4 +487,18 @@ def make_distributed_two_pass_multi(mesh, *, ls, salt, k, chunk,
             out_specs=(P(axis_name), P(axis_name), P(axis_name)),
         )(keys, weights)
 
-    return jax.jit(program)
+    return TwoPassProgram(jax.jit(program))
+
+
+class TwoPassProgram:
+    """The jitted two-pass program, each call a ``twopass.job`` span whose
+    compile is kept as the ``two_pass_multi`` entry (``obs.stage_map``).
+    ``jitted`` is the ``jax.jit`` object; ``lower`` is its own."""
+
+    def __init__(self, jitted):
+        self.jitted = jitted
+        self.lower = jitted.lower
+
+    def __call__(self, keys, weights):
+        with obs.span("twopass.job"):
+            return obs.dispatch("two_pass_multi", self.jitted, keys, weights)

@@ -47,6 +47,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from ..kernels.capscore.ops import capscore_agg, capscore_multi
 from .samplers import SampleResult
 from . import segments as SG
@@ -360,13 +361,15 @@ def _multi_chunk_step(table, bk_keys, bk_seeds, pos, ck, cw, l, salt,
     """
     cap_bk = bk_keys.shape[-1]
     max_evict = spec.evict_every * spec.chunk
-    eids = spec.eids(pos)
-    # the ONE chunk sort, with the pre-gathered view for ordered scoring
-    order = chunk_order(ck, eids, cw, sort_backend=spec.sort_route)
-    # fused: score every l lane AND reduce to per-key columns in one pass
-    w_total, entered, contrib, kb_min, min_score = capscore_agg(
-        order.ks, order.eids, order.ws, order.seg, l, table.tau,
-        salt, backend=spec.backend)
+    with obs.stage("chunk_sort"):
+        eids = spec.eids(pos)
+        # the ONE chunk sort, with the pre-gathered view for ordered scoring
+        order = chunk_order(ck, eids, cw, sort_backend=spec.sort_route)
+    with obs.stage("score"):
+        # fused: score every l lane AND reduce to per-key columns in one pass
+        w_total, entered, contrib, kb_min, min_score = capscore_agg(
+            order.ks, order.eids, order.ws, order.seg, l, table.tau,
+            salt, backend=spec.backend)
 
     def lane_merge(tab, en, ct, kbm, ms):
         # l is already baked into the per-lane aggregate columns; the
@@ -375,19 +378,23 @@ def _multi_chunk_step(table, bk_keys, bk_seeds, pos, ck, cw, l, salt,
                           contrib=ct, kb=kbm, min_score=ms)
         return VZ.fixed_k_merge(tab, agg)
 
-    table = jax.vmap(lane_merge)(table, entered, contrib, kb_min, min_score)
-    table = _scheduled_evict(
-        table, spec,
-        lambda t: jax.vmap(
-            lambda tab, ll: VZ.evict_table(tab, k=spec.k, l=ll, salt=salt,
-                                           max_evict=max_evict)
-        )(t, l))
-    # min_score doubles as the (already key-ordered) pass-1 chunk
-    # summary; the key-sorted carry folds it in sort-free
-    bk_keys, bk_seeds = jax.vmap(
-        lambda sk, ss, mn: VZ.pass1_fold_keysorted(sk, ss, order.ukeys,
-                                                   mn, cap_bk)
-    )(bk_keys, bk_seeds, min_score)
+    with obs.stage("merge"):
+        table = jax.vmap(lane_merge)(table, entered, contrib, kb_min,
+                                     min_score)
+    with obs.stage("evict"):
+        table = _scheduled_evict(
+            table, spec,
+            lambda t: jax.vmap(
+                lambda tab, ll: VZ.evict_table(tab, k=spec.k, l=ll,
+                                               salt=salt, max_evict=max_evict)
+            )(t, l))
+    with obs.stage("fold"):
+        # min_score doubles as the (already key-ordered) pass-1 chunk
+        # summary; the key-sorted carry folds it in sort-free
+        bk_keys, bk_seeds = jax.vmap(
+            lambda sk, ss, mn: VZ.pass1_fold_keysorted(sk, ss, order.ukeys,
+                                                       mn, cap_bk)
+        )(bk_keys, bk_seeds, min_score)
     return table, bk_keys, bk_seeds, pos + spec.chunk
 
 
@@ -408,7 +415,9 @@ def _update_multi_impl(state: SamplerState, keys, weights, spec: SamplerSpec) ->
     wc = weights.reshape(n // chunk, chunk)
 
     cap_bk = state.bk_keys.shape[1]
-    bkk0, bks0 = jax.vmap(VZ.summary_to_keysorted)(state.bk_keys, state.bk_seeds)
+    with obs.stage("to_keysorted"):
+        bkk0, bks0 = jax.vmap(VZ.summary_to_keysorted)(state.bk_keys,
+                                                       state.bk_seeds)
 
     def body(carry, xs):
         table, bk_keys, bk_seeds, pos = carry
@@ -419,8 +428,9 @@ def _update_multi_impl(state: SamplerState, keys, weights, spec: SamplerSpec) ->
 
     (table, bkk, bks, pos), _ = jax.lax.scan(
         body, (state.table, bkk0, bks0, state.n_seen), (kc, wc))
-    bk_keys, bk_seeds = jax.vmap(
-        lambda kk, ss: VZ.summary_from_keysorted(kk, ss, cap_bk))(bkk, bks)
+    with obs.stage("from_keysorted"):
+        bk_keys, bk_seeds = jax.vmap(
+            lambda kk, ss: VZ.summary_from_keysorted(kk, ss, cap_bk))(bkk, bks)
     return SamplerState(table, pos, state.l, state.salt, bk_keys, bk_seeds)
 
 
@@ -486,11 +496,13 @@ def update_multi(state: SamplerState, keys, weights, spec: SamplerSpec, *,
     ``reference=True`` routes through the pre-single-sort step (bit-identical
     results at evict_every=1, strictly slower) — benchmarking/testing only.
     """
+    args = (state, jnp.asarray(keys), jnp.asarray(weights), spec)
     if reference:
         fn = _update_multi_ref_donated if donate else _update_multi_ref_fresh
-    else:
-        fn = _update_multi_donated if donate else _update_multi_fresh
-    return fn(state, jnp.asarray(keys), jnp.asarray(weights), spec)
+        return fn(*args)
+    if donate:
+        return obs.dispatch("update_multi", _update_multi_donated, *args)
+    return _update_multi_fresh(*args)
 
 
 def finalize_multi(state: SamplerState, spec: SamplerSpec,
@@ -590,8 +602,9 @@ def _update_bank_impl(state: SamplerState, keys, weights, active,
     tests/test_serving.py).
     """
     cap_bk = state.bk_keys.shape[-1]
-    bkk0, bks0 = jax.vmap(jax.vmap(VZ.summary_to_keysorted))(
-        state.bk_keys, state.bk_seeds)
+    with obs.stage("to_keysorted"):
+        bkk0, bks0 = jax.vmap(jax.vmap(VZ.summary_to_keysorted))(
+            state.bk_keys, state.bk_seeds)
 
     def tenant_step(table, bkk, bks, pos, ck, cw, salt):
         return _multi_chunk_step(table, bkk, bks, pos, ck, cw, state.l, salt,
@@ -599,13 +612,16 @@ def _update_bank_impl(state: SamplerState, keys, weights, active,
 
     table, bkk, bks, pos = jax.vmap(tenant_step)(
         state.table, bkk0, bks0, state.n_seen, keys, weights, state.salt)
-    bk_keys, bk_seeds = jax.vmap(jax.vmap(
-        lambda kk, ss: VZ.summary_from_keysorted(kk, ss, cap_bk)))(bkk, bks)
+    with obs.stage("from_keysorted"):
+        bk_keys, bk_seeds = jax.vmap(jax.vmap(
+            lambda kk, ss: VZ.summary_from_keysorted(kk, ss, cap_bk)))(bkk,
+                                                                        bks)
 
-    table = _mask_tenants(active, table, state.table)
-    bk_keys = _mask_tenants(active, bk_keys, state.bk_keys)
-    bk_seeds = _mask_tenants(active, bk_seeds, state.bk_seeds)
-    pos = jnp.where(active, pos, state.n_seen)
+    with obs.stage("mask_tenants"):
+        table = _mask_tenants(active, table, state.table)
+        bk_keys = _mask_tenants(active, bk_keys, state.bk_keys)
+        bk_seeds = _mask_tenants(active, bk_seeds, state.bk_seeds)
+        pos = jnp.where(active, pos, state.n_seen)
     return SamplerState(table, pos, state.l, state.salt, bk_keys, bk_seeds)
 
 
@@ -620,9 +636,11 @@ def update_bank(state: SamplerState, keys, weights, active, spec: SamplerSpec,
     """Advance every active tenant's l-grid by one chunk: one device dispatch
     for the whole bank.  Same donation contract as ``update``/``update_multi``.
     """
-    fn = _update_bank_donated if donate else _update_bank_fresh
-    return fn(state, jnp.asarray(keys), jnp.asarray(weights),
-              jnp.asarray(active), spec)
+    args = (state, jnp.asarray(keys), jnp.asarray(weights),
+            jnp.asarray(active), spec)
+    if donate:
+        return obs.dispatch("update_bank", _update_bank_donated, *args)
+    return _update_bank_fresh(*args)
 
 
 # reprolint: disable=RPL003 -- non-destructive projection (see _final_evict)
@@ -814,8 +832,10 @@ class MultiSampler:
     def observe(self, keys, weights=None) -> None:
         keys = normalize_keys(keys)
         self._n_real += len(keys)
+        obs.count("ingest.elements", len(keys))
         bk, bw = self._rem.add(keys, weights)
         if bk is not None:
+            obs.count("ingest.chunks", len(bk) // self.spec.chunk)
             self.state = update_multi(self.state, bk, bw, self.spec)
 
     def flushed_state(self) -> SamplerState:
@@ -1037,10 +1057,11 @@ class TenantBank:
                  evict_every=1, backend=None, sort_backend=None):
         self.ls = tuple(float(l) for l in ls)
         self.n_tenants = int(n_tenants)
-        self.state, self.spec = init_bank_state(
-            ls, n_tenants=n_tenants, k=k, chunk=chunk, salts=salts,
-            host_id=host_id, evict_every=evict_every, backend=backend,
-            sort_backend=sort_backend)
+        with obs.span("bank.build"):
+            self.state, self.spec = init_bank_state(
+                ls, n_tenants=n_tenants, k=k, chunk=chunk, salts=salts,
+                host_id=host_id, evict_every=evict_every, backend=backend,
+                sort_backend=sort_backend)
         self._queues = [_PendingQueue() for _ in range(self.n_tenants)]
         self._n_real = np.zeros(self.n_tenants, np.int64)
 
@@ -1068,12 +1089,18 @@ class TenantBank:
         active = np.asarray([q.size >= chunk for q in self._queues])
         if not active.any():
             return 0
-        K = np.full((self.n_tenants, chunk), _EMPTY_INT, np.int32)
-        W = np.zeros((self.n_tenants, chunk), np.float32)
-        for t in np.nonzero(active)[0]:
-            K[t], W[t] = self._queues[t].take(chunk)
-        self.state = update_bank(self.state, K, W, active, self.spec)
-        return int(active.sum())
+        n_active = int(active.sum())
+        with obs.span("bank.tick"):
+            K = np.full((self.n_tenants, chunk), _EMPTY_INT, np.int32)
+            W = np.zeros((self.n_tenants, chunk), np.float32)
+            for t in np.nonzero(active)[0]:
+                K[t], W[t] = self._queues[t].take(chunk)
+            self.state = update_bank(self.state, K, W, active, self.spec)
+        # every tenant's lane runs in the vmapped tick; the active carry
+        # a real chunk (their ratio is the tick's useful share)
+        obs.count("bank.lanes_run", self.n_tenants)
+        obs.count("bank.lanes_real", n_active)
+        return n_active
 
     def drain(self) -> int:
         """Tick until no tenant holds a full chunk; returns ticks issued."""

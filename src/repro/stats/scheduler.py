@@ -34,6 +34,7 @@ from typing import Callable
 
 import numpy as np
 
+from .. import obs
 from ..core import freqfns
 from .service import MultiTenantStats, TenantQuery
 
@@ -207,10 +208,16 @@ class StatsScheduler:
         Order is the overlap contract (module docstring): admit → refresh
         (only when due AND queries are waiting) → enqueue the coalesced
         query dispatch → enqueue the stacked ingest tick(s) → block on the
-        query result only.
+        query result only.  The step is a ``scheduler.step`` span (its
+        number the request id) with children ``scheduler.admit``,
+        ``scheduler.refresh``, ``scheduler.query`` and ``scheduler.tick``.
         """
-        cfg = self.config
         self.n_steps += 1
+        with obs.span("scheduler.step", request=self.n_steps):
+            return self._step()
+
+    def _step(self) -> list[int]:
+        cfg = self.config
         T = self.service.n_tenants
 
         # 0) expire abandoned results: records not popped within the TTL
@@ -222,19 +229,20 @@ class StatsScheduler:
                 del self._results[rid]
             self.n_results_expired += len(expired)
 
-        # 1) admit ingest fairly into the bank's staging queues (host-side
-        #    numpy appends — no device work yet).
-        admitted = _round_robin(self._ingest_q, self._rr_ingest, T,
-                                cfg.max_ingest_per_step)
-        self._rr_ingest = (self._rr_ingest + 1) % max(T, 1)
-        for tenant, (keys, weights) in admitted:
-            self.service.observe(tenant, keys, weights)
-            self.n_elements_ingested += int(np.asarray(keys).size)
+        with obs.span("scheduler.admit"):
+            # 1) admit ingest fairly into the bank's staging queues
+            #    (host-side numpy appends — no device work yet).
+            admitted = _round_robin(self._ingest_q, self._rr_ingest, T,
+                                    cfg.max_ingest_per_step)
+            self._rr_ingest = (self._rr_ingest + 1) % max(T, 1)
+            for tenant, (keys, weights) in admitted:
+                self.service.observe(tenant, keys, weights)
+                self.n_elements_ingested += int(np.asarray(keys).size)
 
-        # 2) admit queries fairly and coalesce across tenants.
-        picked = _round_robin(self._query_q, self._rr_query, T,
-                              cfg.max_queries_per_step)
-        self._rr_query = (self._rr_query + 1) % max(T, 1)
+            # 2) admit queries fairly and coalesce across tenants.
+            picked = _round_robin(self._query_q, self._rr_query, T,
+                                  cfg.max_queries_per_step)
+            self._rr_query = (self._rr_query + 1) % max(T, 1)
 
         # 3) refresh the snapshot only when it pays: queries are waiting
         #    and the snapshot is stale and the cadence is due (or there is
@@ -245,25 +253,29 @@ class StatsScheduler:
         if picked and self.service.stale and (
                 self._steps_since_refresh >= cfg.refresh_every
                 or not self.service.has_engine):
-            self.service.refresh(tenants={t for t, _ in picked})
+            with obs.span("scheduler.refresh"):
+                self.service.refresh(tenants={t for t, _ in picked})
             self._steps_since_refresh = 0
 
         # 4) enqueue the ONE coalesced query dispatch (no host sync).
         pending = None
         if picked:
-            pending = self.service.query_batch_async(
-                [tq for _, (_, tq, _) in picked], auto_refresh=False)
+            with obs.span("scheduler.query"):
+                pending = self.service.query_batch_async(
+                    [tq for _, (_, tq, _) in picked], auto_refresh=False)
 
         # 5) enqueue the stacked ingest tick(s): device work for tick t+1
         #    runs while the query batch is still in flight.
-        for _ in range(cfg.max_ticks_per_step):
-            if self.service.tick() == 0:
-                break
+        with obs.span("scheduler.tick"):
+            for _ in range(cfg.max_ticks_per_step):
+                if self.service.tick() == 0:
+                    break
 
         # 6) block — on the query result only.
         done: list[int] = []
         if pending is not None:
-            batch = pending.result()
+            with obs.span("scheduler.query"):
+                batch = pending.result()
             now = self._clock()
             for j, (tenant, (rid, _tq, t_submit)) in enumerate(picked):
                 self._results[rid] = QueryRecord(

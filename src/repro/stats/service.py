@@ -70,6 +70,7 @@ from typing import Sequence
 
 import numpy as np
 
+from .. import obs
 from ..checkpoint import manager as ckpt_manager
 from ..core import freqfns, incremental
 from ..core.samplers import SampleResult
@@ -144,11 +145,13 @@ class StreamStatsService:
 
     def __init__(self, config: StatsConfig):
         self.config = config
-        self._sampler = incremental.MultiSampler(
-            tuple(float(l) for l in config.ls), k=config.k,
-            chunk=config.chunk, salt=config.salt, host_id=config.host_id,
-            evict_every=config.evict_every, backend=config.ingest_backend,
-        )
+        with obs.span("service.build"):
+            self._sampler = incremental.MultiSampler(
+                tuple(float(l) for l in config.ls), k=config.k,
+                chunk=config.chunk, salt=config.salt, host_id=config.host_id,
+                evict_every=config.evict_every,
+                backend=config.ingest_backend,
+            )
         self._results: dict[float, SampleResult] | None = None
         self._engines: dict[bool, QueryEngine] = {}  # query plane, per path
         self._lanes: list[_LaneSample] | None = None  # frozen pass-1 samples
@@ -174,7 +177,8 @@ class StreamStatsService:
         validated through the same ``incremental.normalize_keys`` helper as
         ``reconcile`` — never silently wrapped to int32.
         """
-        self._sampler.observe(keys, weights)
+        with obs.span("service.observe"):
+            self._sampler.observe(keys, weights)
         self._results = None
         self._engines.clear()
         self._invalidate_reconcile()

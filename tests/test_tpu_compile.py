@@ -10,11 +10,14 @@ The topology is described only inside the ``topo`` fixture (never at
 import): only one process at a time may load the TPU library, and every
 test worker imports this file.  All such compiles live in this one file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro import obs
 from repro.core import incremental as I
 from repro.kernels.capscore import capscore as CS
 from repro.kernels.capscore.tiling import tile_config
@@ -122,6 +125,20 @@ def test_multi_lane_chunk_step_compiles_for_v5e(one_chip, monkeypatch):
                     _sds(one_chip, (n,), jnp.float32))
     # one block sort + three merge passes (2048 = 256 * 2^3) + the aggregate
     assert text.count("tpu_custom_call") >= 5
+    # every chunk-step stage scope reaches the compiled program's metadata,
+    # where the benchmark's stage readers find it (repro.obs.stage_map)
+    for name in obs.CHUNK_STAGES:
+        assert f"/{name}/" in text, name
+    assert set(obs.parse_stage_map(text).stages.values()) >= set(
+        obs.CHUNK_STAGES)
+    # the Mosaic kernels keep the instruction names the benchmark's kernel
+    # readers match in a device trace
+    from bench.harness.readers import KERNEL_PATTERNS
+
+    kernels = re.findall(r"^\s+(?:ROOT )?%?([^\s=]+) = .*custom_call_target="
+                         r'"tpu_custom_call"', text, re.M)
+    for pat in ("capscore_agg", "chunksort"):
+        assert any(KERNEL_PATTERNS[pat].search(k) for k in kernels), pat
 
 
 def test_distributed_two_pass_compiles_for_v5e_2x2(topo, monkeypatch):
@@ -144,3 +161,5 @@ def test_distributed_two_pass_compiles_for_v5e_2x2(topo, monkeypatch):
                     _sds(shard, (n,), jnp.float32)).compile().as_text()
     assert "tpu_custom_call" in text
     assert "collective-permute" in text and "all-reduce" in text
+    assert set(obs.parse_stage_map(text).stages.values()) == set(
+        obs.TWO_PASS_STAGES)
