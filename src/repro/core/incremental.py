@@ -835,7 +835,10 @@ class MultiSampler:
         obs.count("ingest.elements", len(keys))
         bk, bw = self._rem.add(keys, weights)
         if bk is not None:
-            obs.count("ingest.chunks", len(bk) // self.spec.chunk)
+            n_chunks = len(bk) // self.spec.chunk
+            obs.count("ingest.chunks", n_chunks)
+            if VZ.merge_form() == "sort":
+                obs.count("merge.sort_route", n_chunks * len(self.ls))
             self.state = update_multi(self.state, bk, bw, self.spec)
 
     def flushed_state(self) -> SamplerState:
@@ -1100,6 +1103,8 @@ class TenantBank:
         # a real chunk (their ratio is the tick's useful share)
         obs.count("bank.lanes_run", self.n_tenants)
         obs.count("bank.lanes_real", n_active)
+        if VZ.merge_form() == "sort":
+            obs.count("merge.sort_route", self.n_tenants * len(self.ls))
         return n_active
 
     def drain(self) -> int:

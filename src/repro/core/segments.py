@@ -380,6 +380,10 @@ def merge_sorted_runs_gather(a, b, out_len: int | None = None):
     unique, so marking them and prefix-summing yields exactly the same
     integers — and XLA:CPU runs the scatter+cumsum ~4x faster than a binary
     search whose queries are the full iota.
+
+    These are XLA:CPU timings.  On the TPU, where XLA lowers element-wise
+    dynamic indexing slowly, the table merge takes the sort form instead
+    (``vectorized._merge_runs_by_sort``).
     """
     na, nb = a.shape[0], b.shape[0]
     m = na + nb if out_len is None else min(out_len, na + nb)
@@ -402,7 +406,9 @@ def searchsorted(a, v, side: str = "left"):
     picks the loop form — but the unrolled binary search avoids XLA:CPU's
     per-iteration while-loop thunk overhead (~20% on the rank passes that
     dominate the sorted-runs merges).  All hot-path rank computations go
-    through here.
+    through here.  That is an XLA:CPU timing; on the TPU the table merge
+    searches nothing (it takes the sort form,
+    ``vectorized._merge_runs_by_sort``).
     """
     return jnp.searchsorted(a, v, side=side, method="scan_unrolled")
 
@@ -467,7 +473,10 @@ def compact_valid(valid, *arrays, fills):
     where iota-query binary searches lower poorly.  Payload columns then pay
     one cheap gather each.  Order-preserving: compacting an ascending array
     yields an ascending array, which is what maintains the sorted-table
-    invariant of core.vectorized.
+    invariant of core.vectorized.  The costs quoted are XLA:CPU's; on the
+    TPU the table merge compacts by a sort instead
+    (``vectorized._merge_runs_by_sort``), while the eviction still comes
+    here.
     """
     n = valid.shape[0]
     cs = jnp.cumsum(valid)

@@ -369,9 +369,16 @@ def _merge_table(state: TableState, agg: ChunkAgg):
     return _merge_reduce(ks, st, cn, wt, en, ct, kb, sd)
 
 
-def _merge_table_sorted(state: TableState, agg: ChunkAgg):
-    """``_merge_table`` as a pairwise two-sorted-runs merge — no sort, no
-    segment ops.
+def merge_form() -> str:
+    """The table merge's form on this platform, decided at trace time:
+    ``'sort'`` on the TPU, ``'gather'`` everywhere else (see
+    ``_merge_table_sorted``)."""
+    return "sort" if jax.default_backend() == "tpu" else "gather"
+
+
+def _merge_table_sorted(state: TableState, agg: ChunkAgg, *,
+                        form: str = "auto"):
+    """``_merge_table`` as a pairwise two-sorted-runs merge — no segment ops.
 
     Requires the **sorted-table invariant**: ``state.keys`` ascending, unique,
     with all EMPTY slots compacted to the back (established at init, preserved
@@ -381,15 +388,29 @@ def _merge_table_sorted(state: TableState, agg: ChunkAgg):
     Because BOTH runs hold unique keys, every "segment" of the merged union
     has at most two members — one table entry, one chunk aggregate — so the
     general segment-reduce machinery of ``_merge_reduce`` collapses to a
-    gather-and-combine: match the runs against each other with two
-    ``searchsorted`` rank passes, add/min the matched payloads directly,
-    compact the genuinely new keys, and scatter both runs into their merged
-    positions.  O(N) gathers/scatters + O(C log cap) binary searches per lane
-    per chunk, versus the reference's O(N log N) sort + seven scatter-based
-    segment reductions.  Bit-identical to ``_merge_table`` (the reductions it
-    replaces touch at most two values per key: float adds against 0.0 and
+    pairwise combine.  ``form`` picks how the runs are matched and
+    interleaved (``'auto'``: ``merge_form()``):
+
+    * ``'gather'``: match the runs against each other with two
+      ``searchsorted`` rank passes, add/min the matched payloads directly,
+      compact the genuinely new keys, and gather both runs into their merged
+      positions.  O(N) gathers/scatters + O(C log cap) binary searches per
+      lane per chunk — the fast form on XLA:CPU, where gathers are cheap.
+    * ``'sort'``: two batched sorts with the payloads as sort operands
+      (``_merge_runs_by_sort``) — the TPU's form, where XLA lowers
+      element-wise dynamic indexing slowly and sorts natively.
+
+    Both forms are bit-identical to ``_merge_table`` (the reductions they
+    replace touch at most two values per key: float adds against 0.0 and
     mins against inf are exact).
     """
+    if form == "auto":
+        form = merge_form()
+    if form == "sort":
+        return _merge_runs_by_sort(state, agg)
+    if form != "gather":
+        raise ValueError(f"unknown merge form {form!r}: use 'auto', 'gather' "
+                         "or 'sort'")
     cap = state.keys.shape[0]
     C = agg.ukeys.shape[0]
     inf = jnp.float32(jnp.inf)
@@ -427,6 +448,61 @@ def _merge_table_sorted(state: TableState, agg: ChunkAgg):
     n_valid = (jnp.sum(a_live.astype(jnp.int32))
                + jnp.sum(new.astype(jnp.int32)))
     return keys_c, counts_c, kb_c, sd_c, n_valid
+
+
+def _merge_runs_by_sort(state: TableState, agg: ChunkAgg):
+    """The sort form of ``_merge_table_sorted``: no gathers, no scatters.
+
+    1. One sort of the concatenated runs by (key, run), the payload columns
+       riding as sort operands.  ``run`` is 0 for a table entry, 1 for a
+       chunk key that entered and 2 for one that did not, so a key held by
+       both runs sits as a (table, chunk) pair of neighbours.
+    2. Combine with neighbour comparisons (shifted slices): a table entry
+       followed by its chunk match takes ``count + w_total`` and the mins.
+    3. Drop chunk entries that matched or did not enter, and EMPTY slots:
+       their key becomes EMPTY and their payload the fill (0, inf, inf).
+    4. A second sort by key compacts the kept entries to the front.
+
+    Exact without a stable sort: live (key, run) pairs are unique, and every
+    dropped entry carries the same fill.
+    """
+    cap = state.keys.shape[0]
+    inf = jnp.float32(jnp.inf)
+    run = jnp.concatenate([jnp.zeros((cap,), jnp.int32),
+                           jnp.where(agg.entered, 1, 2).astype(jnp.int32)])
+    # reprolint: disable=RPL002 -- the TPU's merge form, taken only where
+    # merge_form() says 'sort'; XLA:CPU keeps the gather form
+    ks, run, cn, wt, kb, sd = jax.lax.sort(
+        (jnp.concatenate([state.keys, agg.ukeys]), run,
+         jnp.concatenate([state.counts, agg.contrib]),
+         jnp.concatenate([jnp.zeros((cap,), jnp.float32), agg.w_total]),
+         jnp.concatenate([state.kb, agg.kb]),
+         jnp.concatenate([state.seed, agg.min_score])),
+        num_keys=2, is_stable=False)
+
+    def nxt(x, fill):
+        return jnp.concatenate([x[1:], jnp.full((1,), fill, x.dtype)])
+
+    live = is_live(ks)
+    from_table = run == 0
+    eq = ks[1:] == ks[:-1]
+    same_next = jnp.concatenate([eq, jnp.zeros((1,), bool)])
+    same_prev = jnp.concatenate([jnp.zeros((1,), bool), eq])
+    # table entries matched by the chunk (cached-key branch: count += chunk
+    # total weight, kb/seed min with the chunk's); new chunk keys keep their
+    # own columns (inserted iff an entry event happened)
+    hit = from_table & live & same_next
+    counts = cn + jnp.where(hit, nxt(wt, 0.0), 0.0)
+    kb_m = jnp.minimum(kb, jnp.where(hit, nxt(kb, inf), inf))
+    sd_m = jnp.minimum(sd, jnp.where(hit, nxt(sd, inf), inf))
+    keep = live & (from_table | ((run == 1) & ~same_prev))
+    # reprolint: disable=RPL002 -- the TPU's merge form (see above)
+    out = jax.lax.sort(
+        (jnp.where(keep, ks, EMPTY), jnp.where(keep, counts, 0.0),
+         jnp.where(keep, kb_m, inf), jnp.where(keep, sd_m, inf)),
+        num_keys=1, is_stable=False)
+    keys_c, counts_c, kb_c, sd_c = (x[:cap] for x in out)
+    return keys_c, counts_c, kb_c, sd_c, jnp.sum(keep.astype(jnp.int32))
 
 
 # ---------------------------------------------------------------------------

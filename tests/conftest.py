@@ -17,3 +17,21 @@ def zipf_stream():
 def zipf_truth(zipf_stream):
     ukeys, cnts = np.unique(zipf_stream, return_counts=True)
     return ukeys, cnts
+
+
+@pytest.fixture
+def force_merge_form(monkeypatch):
+    """``force(form)`` steers the table merge's platform resolver
+    (``vectorized.merge_form``) to ``'gather'`` or ``'sort'``.  Compiled
+    programs are dropped at each switch and at teardown, so no program
+    traced under one form is reused under the other."""
+    import jax
+
+    from repro.core import vectorized as V
+
+    def force(form):
+        jax.clear_caches()
+        monkeypatch.setattr(V, "merge_form", lambda: form)
+
+    yield force
+    jax.clear_caches()

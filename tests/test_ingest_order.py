@@ -26,6 +26,7 @@ Contracts under test:
 * the capscore interpret default derives from the backend with env override;
 * the kernel pad helper: padded-vs-aligned outputs slice bit-identically.
 """
+import functools
 import math
 
 import numpy as np
@@ -280,6 +281,150 @@ def test_merge_sorted_runs_gather_out_len_prefix():
                                                   out_len)
             merged = np.where(np.asarray(fb), b[np.asarray(ib)], a[np.asarray(ia)])
             np.testing.assert_array_equal(merged, ref[: len(merged)])
+
+
+# ---------------------------------------------------------------------------
+# the table merge's two forms: gather (XLA:CPU) and two batched sorts (TPU)
+# ---------------------------------------------------------------------------
+
+
+def _bits(x):
+    x = np.asarray(x)
+    return x.view(np.uint32) if x.dtype == np.float32 else x
+
+
+def _zipf_chunk(rng, chunk, universe=10**8):
+    """A Zipf(1.3) chunk of int32 keys over a 10^8-key universe."""
+    return jnp.asarray((rng.zipf(1.3, chunk) % universe).astype(np.int32))
+
+
+_aggregate_jit = jax.jit(V.aggregate_continuous)
+_fixed_k_step_jit = jax.jit(V.fixed_k_step, static_argnames=("k",))
+_fixed_tau_step_jit = jax.jit(V.fixed_tau_step, static_argnames=("kind",))
+_merge_table_jit = jax.jit(V._merge_table)
+
+
+@functools.lru_cache(maxsize=None)
+def _merge_inputs(case):
+    """The (table, chunk aggregate) pairs one merge-form case feeds."""
+    return tuple(_merge_input_gen(case))
+
+
+def _merge_input_gen(case):
+    rng = np.random.default_rng(31)
+    l, salt = jnp.float32(4.0), jnp.uint32(3)
+
+    def agg_of(table, ck, i):
+        chunk = ck.shape[0]
+        eids = jnp.arange(i * chunk, (i + 1) * chunk, dtype=jnp.int32)
+        return _aggregate_jit(ck, jnp.ones(chunk, jnp.float32), eids,
+                              table.tau, l, salt), eids
+
+    if case.startswith("fixed_k"):
+        k, chunk = map(int, case.split("-")[1:])
+        table = V.init_table(k + chunk)
+        for i in range(8):
+            ck = _zipf_chunk(rng, chunk)
+            agg, eids = agg_of(table, ck, i)
+            yield table, agg
+            table = _fixed_k_step_jit(table, ck, jnp.ones(chunk, jnp.float32),
+                                      eids, l, salt, k=k)
+    elif case == "fixed_tau_overflow":
+        # every key enters (tau far above any score): the union outgrows cap
+        table = V.init_table(256, 10.0)
+        for i in range(4):
+            ck = jnp.asarray(rng.integers(0, 10**8, 128), jnp.int32)
+            agg, eids = agg_of(table, ck, i)
+            yield table, agg
+            table = _fixed_tau_step_jit(table, ck, jnp.ones(128, jnp.float32),
+                                        eids, l, salt, kind="continuous")
+    elif case == "empty_table":
+        table = V.init_table(4096 + 2048)
+        yield table, agg_of(table, _zipf_chunk(rng, 2048), 0)[0]
+    else:
+        # a table of live keys, then a chunk of its own keys, or a chunk
+        # under tau = 0, where no key enters
+        table = V.init_table(512 + 256, 0.5)
+        for i in range(3):
+            ck = _zipf_chunk(rng, 256, universe=5000)
+            table = _fixed_tau_step_jit(table, ck, jnp.ones(256, jnp.float32),
+                                        jnp.arange(i * 256, (i + 1) * 256,
+                                                   dtype=jnp.int32),
+                                        l, salt, kind="continuous")
+        live = np.asarray(table.keys)[np.asarray(V.is_live(table.keys))]
+        if case == "chunk_in_table":
+            ck = jnp.asarray(rng.choice(live, 256), jnp.int32)
+            yield table, agg_of(table, ck, 3)[0]
+        else:
+            ck = jnp.asarray(np.concatenate([live[:128],
+                                             rng.integers(0, 10**8, 128)]),
+                             jnp.int32)
+            agg, _ = agg_of(table._replace(tau=jnp.float32(0.0)), ck, 3)
+            assert not np.asarray(agg.entered).any()
+            yield table, agg
+
+
+@pytest.mark.parametrize("form", ["gather", "sort"])
+@pytest.mark.parametrize("case", [
+    "fixed_k-16-64", "fixed_k-512-2048", "fixed_k-4096-2048",
+    "fixed_tau_overflow", "empty_table", "chunk_in_table", "none_enter"])
+def test_merge_forms_bit_identical_to_concat_sort_merge(case, form):
+    """Both forms of the sorted-runs merge equal the concat-and-re-sort
+    oracle on all five outputs: keys, counts, kb, seed (the first ``cap``
+    slots, bit for bit) and ``n_valid``."""
+    merge = jax.jit(lambda t, a: V._merge_table_sorted(t, a, form=form))
+    over = False
+    for table, agg in _merge_inputs(case):
+        cap = table.keys.shape[0]
+        ref = _merge_table_jit(table, agg)
+        got = merge(table, agg)
+        for g, r in zip(got[:4], ref[:4]):
+            np.testing.assert_array_equal(_bits(g), _bits(r[:cap]))
+        assert int(got[4]) == int(ref[4])
+        over |= int(got[4]) > cap
+    # the fixed-tau case reaches truncation: the union outgrew the table
+    assert over == (case == "fixed_tau_overflow")
+
+
+def _tree_bits(tree):
+    return [_bits(x) for x in jax.tree.leaves(tree)]
+
+
+def _run_update_multi():
+    keys, w = _stream(n=256 * 12, n_keys=900, seed=41)
+    st, spec = I.init_multi_state((1.0, 8.0, 64.0), k=96, chunk=256, salt=5)
+    for b in range(3):
+        sl = slice(b * 1024, (b + 1) * 1024)
+        st = I.update_multi(st, keys[sl].astype(np.int32), w[sl], spec)
+    return st
+
+
+def _run_update_bank():
+    T, chunk = 3, 128
+    keys, w = _stream(n=T * chunk * 6, n_keys=700, seed=43)
+    keys = keys.astype(np.int32).reshape(6, T, chunk)
+    w = w.reshape(6, T, chunk)
+    st, spec = I.init_bank_state((1.0, 16.0), n_tenants=T, k=48, chunk=chunk,
+                                 salts=(1, 2, 3))
+    for tick in range(6):
+        active = np.arange(T) != tick % T        # one tenant idles a tick
+        st = I.update_bank(st, keys[tick], w[tick], active, spec)
+    return st
+
+
+@pytest.mark.parametrize("run", [_run_update_multi, _run_update_bank],
+                         ids=["update_multi", "update_bank"])
+def test_sort_merge_form_end_to_end_bit_identity(run, force_merge_form):
+    """The whole multi-lane batch and the bank tick, compiled with the merge
+    resolver steered to the TPU's sort form, equal the gather form bit for
+    bit: tables (keys, counts, kb, seed, tau) and bottom-(k+1) summaries."""
+    force_merge_form("gather")
+    gathered = run()
+    force_merge_form("sort")
+    sorted_ = run()
+    assert sorted_.table.keys.shape == gathered.table.keys.shape
+    for g, s in zip(_tree_bits(gathered), _tree_bits(sorted_)):
+        np.testing.assert_array_equal(s, g)
 
 
 # ---------------------------------------------------------------------------

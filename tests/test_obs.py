@@ -169,6 +169,31 @@ def test_scheduler_spans_counters_and_bank_stage_map(rec):
         "to_keysorted", "from_keysorted", "mask_tenants"}
 
 
+def test_merge_sort_route_counts_lane_chunks_on_the_sort_form(
+        rec, force_merge_form):
+    """``merge.sort_route`` counts the lane-chunk merges dispatched on the
+    sort form: chunks x lanes a service dispatch, tenants run x lanes a bank
+    tick.  The CPU's own form is the gather form, so it stays 0 there."""
+    cfg = StatsConfig(k=32, ls=(1.0, 8.0, 64.0), chunk=128)
+    svc = StreamStatsService(cfg)
+    T = 3
+    bank = MultiTenantStats(StatsConfig(k=32, ls=(1.0, 8.0), chunk=128),
+                            n_tenants=T)
+    svc.observe(np.arange(512))           # 4 chunks, 3 lanes
+    bank.observe(0, np.arange(128))
+    bank.tick()
+    assert rec.counters["merge.sort_route"] == 0
+    force_merge_form("sort")
+    svc.observe(np.arange(512) + 7)
+    assert rec.counters["merge.sort_route"] == 4 * 3
+    svc.observe(np.arange(100))           # held in the remainder
+    assert rec.counters["merge.sort_route"] == 4 * 3
+    bank.observe(1, np.arange(128))
+    bank.tick()                           # every tenant's lanes run
+    assert rec.counters["merge.sort_route"] == 4 * 3 + T * 2
+    assert rec.counters["ingest.chunks"] == 8
+
+
 def test_two_pass_program_is_a_job_span_with_a_stage_map(rec):
     from jax.sharding import Mesh, NamedSharding, PartitionSpec
 

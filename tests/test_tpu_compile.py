@@ -129,8 +129,13 @@ def test_multi_lane_chunk_step_compiles_for_v5e(one_chip, monkeypatch):
     # where the benchmark's stage readers find it (repro.obs.stage_map)
     for name in obs.CHUNK_STAGES:
         assert f"/{name}/" in text, name
-    assert set(obs.parse_stage_map(text).stages.values()) >= set(
-        obs.CHUNK_STAGES)
+    stages = obs.parse_stage_map(text).stages
+    assert set(stages.values()) >= set(obs.CHUNK_STAGES)
+    # on the TPU the table merge is two batched sorts (no gathers), inside
+    # the merge scope where merge_us reads it
+    sorts = re.findall(r"^\s+(?:ROOT )?%?([^\s=]+) = [^=]*\bsort\(", text,
+                       re.M)
+    assert sorts and any(stages.get(s) == "merge" for s in sorts), sorts
     # the Mosaic kernels keep the instruction names the benchmark's kernel
     # readers match in a device trace
     from bench.harness.readers import KERNEL_PATTERNS
